@@ -52,15 +52,15 @@ bench-routing:
 
 # fast sanity pass CI runs on every matrix entry: cheap analytic sections
 # + the quick simulator / scenario-engine / transient-timeline / latency
-# telemetry benchmarks (covers the fused Pallas row, the K-scenario and
+# telemetry benchmarks (covers the K-scenario and
 # K-schedule one-compile sweeps, the device fault-BFS sweeps and the
 # histogram-overhead rows); exercises the whole bench plumbing
 bench-smoke:
 	PYTHONPATH=src $(PY) -m benchmarks.run --quick \
 	    --only table1,table2,throughput,sim,scenarios,transient,latency,vc,hetero,compose,explore
 
-# the nightly CI job: FULL mode, every section (incl. the fused-parity
-# differential cells in `sim` and the N=4096 sweeps), JSON for the
+# the nightly CI job: FULL mode, every section (incl. the N=4096
+# sweeps), JSON for the
 # dated bench-trend artifact (docs/ci.md "Nightly bench trend")
 bench-nightly:
 	PYTHONPATH=src $(PY) -m benchmarks.run --json $(BENCH_NIGHTLY_JSON)

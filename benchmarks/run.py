@@ -16,6 +16,10 @@ import platform
 import sys
 import traceback
 
+import jax
+
+from repro.compile_cache import configure_compile_cache
+
 from . import (compose_matrix, explore_bench, fig5_8_simulation,
                hetero_links, latency_telemetry, roofline,
                routing_throughput, scenario_sim, sim_throughput,
@@ -58,7 +62,11 @@ def main() -> None:
     if unknown:
         sys.exit(f"unknown section(s): {', '.join(unknown)}; "
                  f"choose from: {', '.join(SECTIONS)}")
-    header()
+    configure_compile_cache()
+    dev = jax.devices()[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(jax.devices())}
+    header(device)
     failed = []
     for name in names:
         try:
@@ -73,6 +81,7 @@ def main() -> None:
             "sections": names,
             "python": platform.python_version(),
             "machine": platform.machine(),
+            "device": device,
         }
         with open(args.json, "w") as f:
             json.dump(doc, f, indent=1, sort_keys=True)

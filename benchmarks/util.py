@@ -55,5 +55,9 @@ def timed(name: str, derived_fn=lambda: ""):
     emit(name, (time.perf_counter() - t0) * 1e6, derived_fn())
 
 
-def header():
+def header(device: dict):
+    """The device line every row below was measured on, then the CSV
+    header."""
+    print(f"# device: platform={device['platform']} kind={device['kind']} "
+          f"count={device['count']}", flush=True)
     print("name,us_per_call,derived", flush=True)

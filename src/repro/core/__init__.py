@@ -18,7 +18,7 @@ from .distances import (DistanceSummary, bcc_average_distance, bcc_diameter,
                         torus_average_distance, weighted_average_distance,
                         weighted_diameter, weighted_distance_matrix)
 from .fault_schedule import CompiledSchedule, FaultSchedule
-from .lattice import LatticeGraph
+from .lattice import InfeasibleNetwork, LatticeGraph
 from .link_spec import LinkSpec
 from .routing import (HierarchicalRouter, fault_aware_next_hop,
                       fault_aware_next_hop_device, make_router,
@@ -49,7 +49,7 @@ from .throughput import (bcc_throughput_bound, channel_load,
                          weighted_saturation_throughput)
 
 __all__ = [
-    "intmat", "LatticeGraph",
+    "intmat", "LatticeGraph", "InfeasibleNetwork",
     "PC", "FCC", "BCC", "RTT", "Torus", "FourD_FCC", "FourD_BCC", "Lip",
     "pc_matrix", "fcc_matrix", "bcc_matrix", "rtt_matrix", "torus_matrix",
     "fourd_fcc_matrix", "fourd_bcc_matrix", "lip_matrix",

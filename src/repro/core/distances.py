@@ -18,7 +18,7 @@ from dataclasses import dataclass
 import numpy as np
 
 from .condition import NetworkCondition
-from .lattice import LatticeGraph
+from .lattice import InfeasibleNetwork, LatticeGraph
 
 
 def _warn_deprecated(old: str, new: str) -> None:
@@ -242,7 +242,7 @@ def _faulted_average_distance(g: LatticeGraph, scenario,
         dist = faulted_distance_matrix(g, scenario)
     d = dist[dist > 0]
     if d.size == 0:
-        raise ValueError("no reachable pairs under this scenario")
+        raise InfeasibleNetwork("no reachable pairs under this scenario")
     return float(d.mean())
 
 
@@ -284,7 +284,7 @@ def _weighted_average_distance(g: LatticeGraph, link_spec,
         dist = weighted_distance_matrix(g, link_spec)
     d = dist[dist > 0]
     if d.size == 0:
-        raise ValueError("no reachable pairs under this LinkSpec")
+        raise InfeasibleNetwork("no reachable pairs under this LinkSpec")
     return float(d.mean())
 
 
@@ -305,7 +305,7 @@ def _matrix_stats(dist: np.ndarray) -> dict:
     facade's summary dict, keeping the shim conventions exactly."""
     d = dist[dist > 0]
     if d.size == 0:
-        raise ValueError("no reachable pairs under this condition")
+        raise InfeasibleNetwork("no reachable pairs under this condition")
     return {"average_distance": float(d.mean()),
             "diameter": int(dist.max()),
             "reachable_pairs": int(d.size)}

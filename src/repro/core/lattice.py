@@ -15,6 +15,14 @@ import numpy as np
 from . import intmat
 
 
+class InfeasibleNetwork(ValueError):
+    """The network leaves nothing to measure or run: fewer than two live
+    nodes, no reachable pair, or a feature combination the simulator does
+    not support.  A `ValueError`, so existing callers that catch that
+    still do; the topology explorer scores exactly these as the worst
+    candidate and lets every other error propagate."""
+
+
 @dataclass(frozen=True)
 class LatticeGraph:
     """G(M): |det M| nodes, regular of degree 2n."""

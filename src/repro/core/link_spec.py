@@ -36,6 +36,8 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from .lattice import InfeasibleNetwork
+
 
 @dataclass(frozen=True)
 class LinkSpec:
@@ -186,7 +188,7 @@ class LinkSpec:
             fwd = np.asarray(g.label_to_index(labels + step), dtype=np.int32)
             bwd = np.asarray(g.label_to_index(labels - step), dtype=np.int32)
             if (fwd == np.arange(g.order)).any():
-                raise ValueError(
+                raise InfeasibleNetwork(
                     f"express (dim={d}, span={s}) folds onto a self-loop "
                     "on this lattice — span matches the cycle length")
             cols.append(np.stack([fwd, bwd], axis=1))

@@ -36,6 +36,7 @@ from __future__ import annotations
 from dataclasses import dataclass, fields, replace
 
 from .fault_schedule import FaultSchedule
+from .lattice import InfeasibleNetwork
 from .link_spec import LinkSpec
 from .scenario import Scenario
 
@@ -64,18 +65,18 @@ def validate_feature_combo(*, impl: str | None = None, vcs: int = 1,
     """
     if impl == "fused":
         if vcs > 1:
-            raise ValueError(
+            raise InfeasibleNetwork(
                 "impl='fused' (the Pallas slot-step kernel) is V=1-only"
                 "; run vcs>1 with impl='batched' or 'reference' (see "
                 "docs/simulator.md, 'Virtual channels & credit flow')")
         if not links_trivial:
-            raise ValueError(
+            raise InfeasibleNetwork(
                 "impl='fused' (the Pallas slot-step kernel) is "
                 "weight-1/no-overlay-only; run heterogeneous "
                 "LinkSpecs with impl='batched' or 'reference' "
                 "(see docs/simulator.md, 'Heterogeneous links')")
     if express and vcs == 1 and policy in ("adaptive", "escape"):
-        raise ValueError(
+        raise InfeasibleNetwork(
             f"express-channel overlays at vcs=1 route with greedy "
             f"weighted DOR only (dead express hops fall back to base "
             f"ports); the V=1 {policy!r} policy scores base-lattice "

@@ -35,10 +35,11 @@ Three implementations of the slot update share the state layout:
   * ``impl="fused"`` — the same slot update as a Pallas kernel
     (`repro.kernels.sim_step`): winner segmented-min, acceptance fixed
     point and the one-hot clears/transit/injection writes fused into ONE
-    kernel pass over VMEM node tiles.  Off-TPU it runs in interpret mode
-    (this container is CPU-only; TPU is the target) and is bitwise-equal
-    to ``batched`` given the same pre-drawn traffic.  Real-TPU lowering
-    is still unvalidated — see the caveat in `kernels/sim_step.py`.
+    kernel pass over VMEM node tiles.  It runs in interpret mode on the
+    CPU and is bitwise-equal to ``batched`` given the same pre-drawn
+    traffic.  It does not lower for TPU (Mosaic refuses its in-kernel
+    gathers — see `kernels/sim_step.py`), so on a TPU backend it raises
+    rather than fall back; the chip runs ``batched``.
   * ``impl="reference"`` — the pre-batching per-port Python loop, kept as
     the semantic oracle: tests validate both other implementations
     statistically against it (same load curves within stochastic
@@ -993,15 +994,21 @@ def _make_slot_step_fused(ctx, warmup: int):
     as ONE kernel pass over VMEM node tiles.  Same state layout and
     pre-drawn traffic as `_make_slot_step_batched`, and bitwise-equal
     results; off-TPU the kernel runs in interpret mode (validated by the
-    differential suite at quick shapes).  Real-TPU lowering is untested
-    in this CPU-only container — see the caveat in kernels/sim_step.py."""
+    differential suite at quick shapes).  On a TPU backend it raises:
+    Mosaic refuses the kernel's in-kernel gathers (see kernels/sim_step.py),
+    and running it anywhere else would hide that the chip never ran it."""
     from ..kernels.ops import _on_tpu
     from ..kernels.sim_step import fused_slot_step
+    if _on_tpu():
+        raise NotImplementedError(
+            'impl="fused" does not lower for TPU: Mosaic refuses the '
+            "kernel's in-kernel gather `sender = nbr[:, opp]` (ValueError: "
+            "Shape mismatch in input, indices and output, from "
+            '_gather_lowering_rule). Use impl="batched" on the chip.')
     N = ctx["N"]
     nbr = ctx["nbr"]
     trivial = ctx["trivial"]
     scheduled = ctx.get("scheduled", False)
-    interpret = not _on_tpu()
 
     def slot_step(state, tr):
         slot = state["slot"]
@@ -1041,7 +1048,7 @@ def _make_slot_step_fused(ctx, warmup: int):
             link_ok=link_ok,
             dst_live_fixed=dst_live,
             policy="dor" if trivial else ctx["policy"],
-            interpret=interpret)
+            interpret=True)
         can = can8 != 0
         drop = None if trivial else (drop8 != 0)
         backlog = backlog0 + want_new - can
@@ -2491,7 +2498,9 @@ def simulate(g: LatticeGraph, pattern: str, load: float, *,
     impl="fused" routes the slot update through the Pallas kernel
     (`repro.kernels.sim_step`): same state layout and pre-drawn traffic as
     the batched path, winner/acceptance/apply fused into one kernel pass
-    (interpret mode off-TPU) — results are bitwise-equal to batched.
+    — results are bitwise-equal to batched.  It runs in interpret mode on
+    the CPU and raises NotImplementedError on a TPU backend, where Mosaic
+    refuses its gathers.
 
     `hist_bins=B` additionally collects the (B,)-bucket latency histogram
     in the scan carry (`SimResult.latency_hist` /

@@ -12,7 +12,7 @@ import numpy as np
 from .condition import NetworkCondition
 from .distances import (_warn_deprecated, bcc_average_distance,
                         fcc_average_distance, pc_average_distance)
-from .lattice import LatticeGraph
+from .lattice import InfeasibleNetwork, LatticeGraph
 
 
 def symmetric_throughput_bound(g: LatticeGraph) -> float:
@@ -226,7 +226,7 @@ def _fault_aware_channel_load(g: LatticeGraph, scenario,
         dist, next_hop = fault_aware_next_hop(g, link_ok, node_ok)
     live = np.flatnonzero(node_ok)
     if live.size < 2:
-        raise ValueError("scenario leaves fewer than 2 live nodes")
+        raise InfeasibleNetwork("scenario leaves fewer than 2 live nodes")
     rng = np.random.default_rng(seed)
     srcs = live[rng.integers(0, live.size, pairs)]
     dsts = live[rng.integers(0, live.size, pairs)]
@@ -323,7 +323,7 @@ def _walk_loads(nbr: np.ndarray, dist: np.ndarray, next_hop: np.ndarray,
     node_ok = np.asarray(node_ok, dtype=bool)
     live = np.flatnonzero(node_ok)
     if live.size < 2:
-        raise ValueError("scenario leaves fewer than 2 live nodes")
+        raise InfeasibleNetwork("scenario leaves fewer than 2 live nodes")
     rng = np.random.default_rng(seed)
     srcs = live[rng.integers(0, live.size, pairs)]
     dsts = live[rng.integers(0, live.size, pairs)]

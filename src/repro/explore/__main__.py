@@ -14,6 +14,8 @@ import argparse
 import json
 import sys
 
+from repro.compile_cache import configure_compile_cache
+
 from .evaluate import EvalSettings
 from .optimizer import explore
 from .pareto import dominates
@@ -61,6 +63,7 @@ def main(argv=None) -> int:
         args.mode = "analytic"
         args.pairs = min(args.pairs, 2048)
 
+    configure_compile_cache()
     settings = EvalSettings(mode=args.mode, load=args.load,
                             pairs=args.pairs, seed=args.seed)
     space = SearchSpace()

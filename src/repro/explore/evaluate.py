@@ -28,8 +28,8 @@ from dataclasses import dataclass, replace
 
 import numpy as np
 
-from repro.core import (FaultSchedule, LatticeGraph, NetworkCondition,
-                        SimConfig, saturation)
+from repro.core import (FaultSchedule, InfeasibleNetwork, LatticeGraph,
+                        NetworkCondition, SimConfig, saturation)
 from repro.core.distances import weighted_distance_matrix
 
 from .pareto import Objectives
@@ -100,9 +100,11 @@ def canonical_schedule(g: LatticeGraph,
 
 class Evaluator:
     """Memoised multi-objective scorer.  `evaluate` returns the
-    `Objectives` for one candidate; failures (a schedule that
-    disconnects the graph, an invalid feature combination) score
-    `Objectives.worst()` rather than killing the search."""
+    `Objectives` for one candidate.  A candidate the network cannot
+    serve (`InfeasibleNetwork`: no reachable pair or live node under the
+    canonical schedule, an unsupported feature combination) scores
+    `Objectives.worst()` rather than killing the search; any other error
+    — a compile, lowering or runtime failure — propagates."""
 
     def __init__(self, settings: EvalSettings | None = None):
         self.settings = settings or EvalSettings()
@@ -168,9 +170,7 @@ class Evaluator:
                    else self._p99_analytic(g, cand, throughput))
             obj = Objectives(throughput=throughput, p99=p99,
                              faulted=faulted)
-        except (ValueError, AssertionError):
-            # disconnected under the canonical schedule / no reachable
-            # pairs / unsupported feature combination → worst, not fatal
+        except InfeasibleNetwork:
             obj = Objectives.worst()
         self.memo[key] = obj
         self._memo_cands.append((cand, obj))

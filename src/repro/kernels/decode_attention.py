@@ -12,9 +12,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
 
 NEG_INF = -1e30
 Q_PAD = 8  # TPU sublane minimum for fp32 tiles
@@ -86,7 +85,7 @@ def decode_attention(q, k, v, position, *, block_k: int = 512,
             pltpu.VMEM((QP, 1), jnp.float32),
             pltpu.VMEM((QP, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(pos, q, k, v)

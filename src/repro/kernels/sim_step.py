@@ -14,19 +14,21 @@ expresses as separate fused families:
   3. **apply** — the one-hot clears + transit + injection where-chains
      writing the next (rec, birth, port) state.
 
-Layout/validation contract (mirrors `repro.kernels.ops`): the wrapper
-runs the kernel in interpret mode off-TPU (`interpret=not _on_tpu()` at
-the call site in `repro.core.simulation`), and the differential suite
-validates it against the `reference` oracle; given identical pre-drawn
-traffic the fused step is bitwise-equal to `impl="batched"`.
+Validation contract: the kernel runs in interpret mode on the CPU, and
+the differential suite validates it against the `reference` oracle;
+given identical pre-drawn traffic the fused step is bitwise-equal to
+`impl="batched"`.
 
-CAVEAT — real-TPU lowering is UNVALIDATED: this container is CPU-only,
-so CI exercises interpret mode exclusively.  The kernel body leans on
-rank-1 iota, multi-index gathers (`flat_rec[sender, in_widx]`) and
-`take_along_axis`, which Mosaic may reject or lower poorly; expect a
-porting pass (2-D iota shims, gather → dynamic-slice loops, halo-tiled
-phases) the first time `interpret=False` runs on hardware.  See the
-ROADMAP fused-kernel frontier item.
+TPU LOWERING FAILS: compiled for a v5e chip with `interpret=False`,
+Mosaic refuses the first in-kernel gather, `sender = nbr[:, opp]` in
+phase 1 (`ValueError: Shape mismatch in input, indices and output`,
+from `_gather_lowering_rule`); the multi-index gathers
+(`flat_rec[sender, in_widx]`, `whas[sender, ports]`) and the
+`take_along_axis` in `gather_port` come next.  So
+`repro.core.simulation._make_slot_step_fused` raises on a TPU backend
+instead of running this kernel, and the chip runs `impl="batched"`.
+Porting it means replacing every data-dependent gather (the neighbour
+maps are fixed permutations per port, which static shifts may serve).
 
 VIRTUAL CHANNELS — this kernel is V=1-only.  The VC credit-flow router
 (``SimConfig(vcs>=2)``) carries an (N, 2n, V, Q) state plus per-(port,
@@ -67,11 +69,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.routing_engine import policy_ports
-
-from ._compat import CompilerParams
 
 
 def _first_port(rec):
@@ -296,7 +296,7 @@ def fused_slot_step(rec, birth, port, prio, slot, want, tr_r, tr_p, tr_v,
         in_specs=[full_spec(a) for a in inputs],
         out_specs=[node_spec(s.shape) for s in out_shapes],
         out_shape=out_shapes,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(*inputs)

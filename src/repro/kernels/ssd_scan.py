@@ -15,9 +15,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -75,7 +74,7 @@ def ssd_intra_chunk(xdt, Adt, Bm, Cm, *, interpret: bool = True):
             jax.ShapeDtypeStruct((BH, nc, P, N), jnp.float32),
             jax.ShapeDtypeStruct((BH, nc, 1, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(xdt, Adt, Bm, Cm)

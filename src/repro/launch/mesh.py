@@ -8,23 +8,19 @@ upgrade path, which is also our elastic-scaling story.
 """
 from __future__ import annotations
 
-from repro.parallel import _compat
-
-_compat.install()     # jax<0.5: publish shard_map/AxisType/make_mesh shims
+import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _compat.make_mesh(
-        shape, axes,
-        axis_types=(_compat.AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")):
     """Small mesh over however many real/forced devices exist."""
-    return _compat.make_mesh(
-        shape, axes, axis_types=(_compat.AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def pod_lattice(num_chips: int):

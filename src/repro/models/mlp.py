@@ -190,10 +190,9 @@ def moe_sharded(p: MoEParams, cfg, x, mesh):
             aux = jax.lax.pmean(aux, dp_axes)
         return yt.reshape(Bl, Sl, D), aux
 
-    from repro.parallel._compat import shard_map
     all_axes = tuple(mesh.axis_names)
     x_spec = P(batch_axes, None, None)
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         local, mesh=mesh,
         in_specs=(x_spec, P(None, None), w_spec, w_spec,
                   P("model", None, fsdp_axis if fsdp_on else None)),

@@ -1,9 +1,3 @@
-from . import _compat
-
-# publish jax.shard_map / jax.sharding.AxisType / make_mesh(axis_types=...)
-# adapters on jax versions that predate them (no-op on modern jax)
-_compat.install()
-
-from . import sharding  # noqa: E402  (sharding may touch the patched API)
-from .sharding import (activation_rules, constrain,  # noqa: E402
-                       make_activation_rules, make_param_specs, named_tree)
+from . import sharding
+from .sharding import (activation_rules, constrain, make_activation_rules,
+                       make_param_specs, named_tree)

@@ -17,8 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ._compat import shard_map
-
 
 def pipeline_apply(layer_fn, stacked_params, x, mesh, *,
                    num_microbatches: int, axis: str = "pipe"):
@@ -67,7 +65,7 @@ def pipeline_apply(layer_fn, stacked_params, x, mesh, *,
         return jax.lax.psum(out, axis)
 
     pspec = jax.tree.map(lambda _: P(axis), stacked_params)
-    y = shard_map(
+    y = jax.shard_map(
         stage, mesh=mesh,
         in_specs=(pspec, P()), out_specs=P(),
         check_vma=False,

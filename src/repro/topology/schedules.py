@@ -26,9 +26,6 @@ import numpy as np
 
 from repro.core import LatticeGraph
 from repro.core.routing import make_router
-from repro.parallel import _compat
-
-_compat.install()     # jax<0.5: callers drive these helpers via shard_map
 
 
 # ---------------------------------------------------------------------------

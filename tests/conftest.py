@@ -8,7 +8,9 @@
    the module names `hypothesis` / `hypothesis.strategies` when the real
    package is not importable, so the property-test modules collect and run
    in network-less environments.  When hypothesis *is* installed it is used
-   unchanged.
+   unchanged, under one profile with no per-example deadline: the first
+   example of a simulator property JIT-compiles, which no fixed deadline
+   survives.
 """
 import os
 import sys
@@ -22,3 +24,9 @@ for p in (_SRC, _HERE):
 import _propcheck  # noqa: E402  (needs _HERE on sys.path)
 
 PROPCHECK_ACTIVE = _propcheck.install()
+
+if not PROPCHECK_ACTIVE:
+    from hypothesis import settings
+
+    settings.register_profile("repro", deadline=None)
+    settings.load_profile("repro")

@@ -34,9 +34,26 @@ def test_unknown_section_is_a_clear_upfront_error():
         assert valid in msg.split("choose from")[1]
 
 
-def test_known_sections_still_run(capsys):
+def test_known_sections_still_run(capsys, monkeypatch):
+    calls = []
+    monkeypatch.setattr(bench_run, "configure_compile_cache",
+                        lambda: calls.append(1))
     util.reset()
     _main(["--only", "table1", "--quick"])
     out = capsys.readouterr().out
+    assert calls == [1]        # the compile cache is placed before any run
+    assert "# device: platform=cpu" in out and "count=" in out
     assert "name,us_per_call,derived" in out
     assert any(r[0].startswith("table1") for r in util.ROWS)
+
+
+def test_json_meta_records_device(tmp_path, monkeypatch):
+    """--json names the device the rows were measured on."""
+    import json
+    monkeypatch.setattr(bench_run, "configure_compile_cache", lambda: None)
+    util.reset()
+    out = tmp_path / "rows.json"
+    _main(["--only", "table1", "--quick", "--json", str(out)])
+    device = json.loads(out.read_text())["meta"]["device"]
+    assert device["platform"] == "cpu"
+    assert device["count"] >= 1 and device["kind"]

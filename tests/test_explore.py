@@ -12,7 +12,7 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-from repro.core import intmat
+from repro.core import InfeasibleNetwork, intmat
 from repro.explore import (Candidate, EvalSettings, Evaluator, Objectives,
                            ParetoArchive, SearchSpace, dominates, explore)
 
@@ -155,6 +155,29 @@ def test_evaluator_memoizes_by_design_point():
     c = SearchSpace().torus_baseline()
     a, b = ev.evaluate(c), ev.evaluate(c)
     assert a == b and ev.evaluations == 1
+
+
+@pytest.mark.parametrize("exc, scored_worst", [
+    (InfeasibleNetwork("no reachable pairs under this condition"), True),
+    (ValueError("Shape mismatch in input, indices and output"), False),
+    (AssertionError("fault-aware walk stepped onto a dead channel"), False),
+    (RuntimeError("RESOURCE_EXHAUSTED: out of memory"), False),
+], ids=["infeasible", "lowering", "invariant", "runtime"])
+def test_evaluator_absorbs_only_infeasible_networks(monkeypatch, exc,
+                                                    scored_worst):
+    """An infeasible network scores worst; a compile, lowering, invariant
+    or runtime failure propagates instead of posing as a bad candidate."""
+    def boom(*_):
+        raise exc
+
+    ev = Evaluator(FAST)
+    monkeypatch.setattr(ev, "_throughput", boom)
+    c = SearchSpace().torus_baseline()
+    if scored_worst:
+        assert ev.evaluate(c) == Objectives.worst()
+    else:
+        with pytest.raises(type(exc)):
+            ev.evaluate(c)
 
 
 def test_worst_candidate_cannot_enter_front():

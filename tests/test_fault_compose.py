@@ -381,8 +381,11 @@ def test_make_ctx_rejects_express_adaptive_like_simconfig():
 def test_recovery_slots_on_vc_link_flap():
     sched = FaultSchedule.link_flap((0, 0), 96, 224,
                                     base=Scenario(policy="adaptive"))
-    r = simulate(G, "uniform", 0.6, slots=384, warmup=0, seed=3,
-                 tables=TAB, vcs=2, schedule=sched, hist_bins=32)
+    # the outage picture below was calibrated on seed 3 of jax's original
+    # threefry stream (the default before jax 0.5)
+    with jax.threefry_partitionable(False):
+        r = simulate(G, "uniform", 0.6, slots=384, warmup=0, seed=3,
+                     tables=TAB, vcs=2, schedule=sched, hist_bins=32)
     tl = r.timeline
     assert tl.lat_hist is not None and tl.lat_hist.shape == (384, 32)
     check_timeline(r)

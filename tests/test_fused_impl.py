@@ -1,7 +1,7 @@
 """Parity suite for the Pallas fused slot step (`impl="fused"`, ISSUE 4).
 
-Quick shapes, interpret mode (this container is CPU-only; the kernel
-compiles for real on TPU).  The contract is two-layered:
+Quick shapes, interpret mode on the CPU (the kernel does not lower for
+TPU, where `impl="fused"` raises).  The contract is two-layered:
 
   * **bitwise vs batched** — the fused kernel consumes the same pre-drawn
     traffic and encodes the same arbitration keys, so its counters must
@@ -136,3 +136,15 @@ def test_fused_sweep_and_scenario_sweep():
 def test_unknown_impl_rejected():
     with pytest.raises(ValueError, match="unknown simulator impl"):
         simulate(G, "uniform", 0.5, impl="pallas", **KW)
+
+
+def test_fused_refuses_tpu_backend(monkeypatch):
+    """On a TPU backend the fused impl raises — Mosaic refuses its
+    gathers — instead of quietly running batched or interpret mode."""
+    from repro.core import simulation
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    # a fresh runner cache, so the slot step is built (and refuses) here
+    monkeypatch.setattr(simulation, "_RUNNER_CACHE", {})
+    with pytest.raises(NotImplementedError, match='impl="batched"'):
+        simulate(G, "uniform", 0.5, impl="fused", **KW)

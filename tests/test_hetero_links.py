@@ -32,7 +32,7 @@ from repro.core.simulation import build_tables, simulate
 
 # the pre-PR goldens live with the VC-router bitwise contract; the
 # trivial-LinkSpec program must reproduce every one of them
-from test_vc_router import _FCC2_HIST, _GOLDEN_CELLS, _GOLDENS
+from test_vc_router import _FCC2_HIST, _GOLDEN_CELLS, _GOLDENS, golden_stream
 
 
 # ---------------------------------------------------------------------------
@@ -44,8 +44,9 @@ def test_trivial_linkspec_bitwise_matches_goldens(cell):
     """`links=LinkSpec()` IS `links=None`: all pre-PR goldens reproduce
     bit for bit (ints and floats compared exactly, not approximately)."""
     g, pattern, load, kw, scen = _GOLDEN_CELLS[cell]
-    r = simulate(g, pattern, load,
-                 config=SimConfig(scenario=scen, links=LinkSpec(), **kw))
+    with golden_stream():
+        r = simulate(g, pattern, load,
+                     config=SimConfig(scenario=scen, links=LinkSpec(), **kw))
     for k, v in _GOLDENS[cell].items():
         got = getattr(r, k)
         if isinstance(v, float):

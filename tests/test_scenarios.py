@@ -33,7 +33,9 @@ from repro.core.simulation import (_RUNNER_CACHE, _sweep_plan, build_tables,
 G = Torus(4, 4, 4, 4)
 TABLES = build_tables(G)
 LOADS = (0.25, 0.6, 0.95)
-SLOTS, SEEDS = 256, 2          # warmup=0: exact conservation every cell
+# warmup=0: exact conservation every cell; four seeds keep the ±5 %
+# differential clear of seed noise on any PRNG stream
+SLOTS, SEEDS = 256, 4
 
 SCENARIOS = {
     "baseline": None,
@@ -246,13 +248,15 @@ def test_random_link_faults_rejects_infeasible_k():
 def test_multi_seed_ci_shrinks_with_k():
     """CI half-width z·s/√k tightens with more seeds (disjoint seed sets;
     fully deterministic, so this is a fixed numerical fact, not a flake):
-    expect ≈ 1/√4 = 0.5× going from k=4 to k=16."""
+    expect ≈ 1/√4 = 0.5× going from k=16 to k=64.  Sixteen seeds per side
+    keep the sample standard deviation itself within ~20 %, so the ratio
+    does not hinge on one PRNG stream (k=4 spread it over 0.4–0.85)."""
     g = BCC(2)
     t = build_tables(g)
     kw = dict(slots=160, warmup=40, seed=0, tables=t)
-    small = simulate_sweep(g, "uniform", (0.5, 0.9), seeds=range(100, 104),
+    small = simulate_sweep(g, "uniform", (0.5, 0.9), seeds=range(100, 116),
                            **kw)
-    big = simulate_sweep(g, "uniform", (0.5, 0.9), seeds=range(200, 216),
+    big = simulate_sweep(g, "uniform", (0.5, 0.9), seeds=range(200, 264),
                          **kw)
     ci_small = small.accepted_ci().mean()
     ci_big = big.accepted_ci().mean()

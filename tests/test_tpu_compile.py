@@ -1,0 +1,90 @@
+"""The simulator's main-path programs compile for a TPU v5e chip.
+
+Nothing runs here: each program is compiled for one chip of a described
+(not attached) v5e:2x2 topology, which raises whatever the chip's
+compiler would refuse and reports the program's device memory.  The
+topology is described inside a fixture, never at import time, so every
+test worker collects the same tests and only the worker that runs this
+file loads the TPU compiler.
+"""
+import os
+
+import jax
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import FaultSchedule, FourD_FCC, Torus
+from repro.core.fault_schedule import ensure_compiled
+from repro.core.simulation import _sweep_plan, build_tables
+
+HBM_BYTES = 16e9          # one v5e chip
+# chip_smoke.py's main path: the paper's §6.2 large pair at full width
+LOADS = (0.2, 0.4, 0.6, 0.8, 1.0)
+SWEEP = dict(slots=288, warmup=64, seed_list=[0, 1], hist_bins=64)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip: keep the cache out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def compile_for_chip(sharding, g, loads, **plan):
+    """Compile the batched sweep runner of `g` for the described chip and
+    return its memory analysis."""
+    runner, state, keys, _, _ = _sweep_plan(
+        g, "uniform", loads, queue=4, seed=0, tables=build_tables(g),
+        impl="batched", scenario=None, **plan)
+    shapes = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                       sharding=sharding), (state, keys))
+    return runner.lower(*shapes).compile().memory_analysis()
+
+
+def device_bytes(m) -> int:
+    return (m.temp_size_in_bytes + m.argument_size_in_bytes
+            + m.output_size_in_bytes)
+
+
+@pytest.mark.parametrize("graph", ["T(16,8,8,8)", "4D-FCC(8)"])
+def test_full_width_sweep_compiles_and_fits(one_chip, graph):
+    g = Torus(16, 8, 8, 8) if graph.startswith("T") else FourD_FCC(8)
+    assert g.order == 8192
+    m = compile_for_chip(one_chip, g, LOADS, **SWEEP)
+    assert 0 < device_bytes(m) < HBM_BYTES, device_bytes(m)
+
+
+def test_vc_flap_runner_compiles(one_chip):
+    """The credit-flow VC router with per-slot epoch gathers and the
+    latency histogram (a 2D torus: the same operations as chip_smoke's
+    4D composed phase, at a tenth of its compile time)."""
+    g = Torus(16, 16)
+    flap = FaultSchedule.link_flap((0, 0), 64, 160)
+    m = compile_for_chip(one_chip, g, (0.5,), slots=256, warmup=0,
+                         seed_list=None, hist_bins=64, vcs=2,
+                         schedules=[ensure_compiled(flap, g, 256)])
+    assert 0 < device_bytes(m) < HBM_BYTES
