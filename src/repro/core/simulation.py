@@ -711,261 +711,269 @@ def _make_slot_step_batched(ctx, warmup: int):
             # injection BACKLOG dies with it too (pending demand is not a
             # packet — clearing it keeps a dead node from injecting while
             # dead, and is a no-op at E=1 where dead nodes never backlog)
-            e = tr["epoch"]
-            link_ok = state["link_ok"][e]
-            inj_ok_e = state["inj_ok"][e]
-            deadq = (birth >= 0) & ~inj_ok_e[:, None, None]
-            qdrop = deadq.sum()
-            birth = jnp.where(deadq, -1, birth)
-            backlog0 = jnp.where(inj_ok_e, state["backlog"], 0)
+            with jax.named_scope("sim.epoch"):
+                e = tr["epoch"]
+                link_ok = state["link_ok"][e]
+                inj_ok_e = state["inj_ok"][e]
+                deadq = (birth >= 0) & ~inj_ok_e[:, None, None]
+                qdrop = deadq.sum()
+                birth = jnp.where(deadq, -1, birth)
+                backlog0 = jnp.where(inj_ok_e, state["backlog"], 0)
         else:
             link_ok = None if trivial else state["link_ok"]
             qdrop = None
             backlog0 = state["backlog"]
-        slot = state["slot"]
-        occ = birth >= 0                                   # (N, P, Q)
-        if weighted:
-            # a packet still paying a multi-slot crossing (wait > 0) sits
-            # in its queue slot — occupying space and in_flight — but is
-            # not yet eligible to request an output port
-            busy, wait = state["busy"], state["wait"]
-            elig = occ & (wait == 0)
-        else:
-            elig = occ
-        if express and not trivial:
-            # liveness-aware greedy weighted DOR: a carried express port
-            # goes stale when its channel dies (and becomes preferable
-            # again when it repairs) — re-consult against the current
-            # masks every slot.  All-live masks reproduce the carried
-            # port (same greedy argmax), keeping forced-mask/pristine
-            # lanes equivalent.
-            port = jnp.where(
-                occ,
-                _next_port_ext_ok(rec, ctx["pdim"], ctx["psgn"],
-                                  ctx["pspan"],
-                                  link_ok[:, None, None, :]
-                                  ).astype(jnp.int8), NO_PORT)
-        elif scheduled and ctx["policy"] != "dor":
-            # adaptive/escape re-consult policy_ports against the CURRENT
-            # epoch's masks: a carried port can go stale when the world
-            # changes under a waiting packet.  With E = 1 the recompute is
-            # the identity (the carried port was this very function of the
-            # same rec/link_ok), keeping the static run bitwise-equal.
-            port = jnp.where(
-                occ,
-                policy_ports(rec, link_ok[:, None, None, :],
-                             ctx["policy"]).astype(jnp.int8), NO_PORT)
-        else:
-            port = jnp.where(occ, port, NO_PORT)
-        if weighted:
-            # the state-carried port survives the wait (the packet still
-            # wants the same hop once eligible); only the ARBITRATION view
-            # hides waiting packets
-            port_flat = jnp.where(elig, port, NO_PORT).reshape(N, PQ)
-        else:
-            port_flat = port.reshape(N, PQ)
+        with jax.named_scope("sim.arbitrate"):
+            slot = state["slot"]
+            occ = birth >= 0                                   # (N, P, Q)
+            if weighted:
+                # a packet still paying a multi-slot crossing (wait > 0) sits
+                # in its queue slot — occupying space and in_flight — but is
+                # not yet eligible to request an output port
+                busy, wait = state["busy"], state["wait"]
+                elig = occ & (wait == 0)
+            else:
+                elig = occ
+            if express and not trivial:
+                # liveness-aware greedy weighted DOR: a carried express port
+                # goes stale when its channel dies (and becomes preferable
+                # again when it repairs) — re-consult against the current
+                # masks every slot.  All-live masks reproduce the carried
+                # port (same greedy argmax), keeping forced-mask/pristine
+                # lanes equivalent.
+                port = jnp.where(
+                    occ,
+                    _next_port_ext_ok(rec, ctx["pdim"], ctx["psgn"],
+                                      ctx["pspan"],
+                                      link_ok[:, None, None, :]
+                                      ).astype(jnp.int8), NO_PORT)
+            elif scheduled and ctx["policy"] != "dor":
+                # adaptive/escape re-consult policy_ports against the CURRENT
+                # epoch's masks: a carried port can go stale when the world
+                # changes under a waiting packet.  With E = 1 the recompute is
+                # the identity (the carried port was this very function of the
+                # same rec/link_ok), keeping the static run bitwise-equal.
+                port = jnp.where(
+                    occ,
+                    policy_ports(rec, link_ok[:, None, None, :],
+                                 ctx["policy"]).astype(jnp.int8), NO_PORT)
+            else:
+                port = jnp.where(occ, port, NO_PORT)
+            if weighted:
+                # the state-carried port survives the wait (the packet still
+                # wants the same hop once eligible); only the ARBITRATION view
+                # hides waiting packets
+                port_flat = jnp.where(elig, port, NO_PORT).reshape(N, PQ)
+            else:
+                port_flat = port.reshape(N, PQ)
 
-        # ---- winner per (node, out-port): segmented min over encoded keys --
-        # segment id = node·2n + requested_port, key = prio·PQ + rot —
-        # pre-drawn 8-bit threefry priorities (tr["prio"]) + a per-slot
-        # rotating tie-break keep the key narrow; priority collisions land
-        # on the rotating tie-break, so they carry no systematic
-        # queue-slot bias.  The segmented reduction is realized as one
-        # fused masked column-min per port bucket (2n static buckets)
-        # rather than jax.ops.segment_min, whose scatter-min lowering XLA
-        # CPU serializes (~17× slower at N=4096); either way every
-        # per-slot intermediate stays O(N·2nQ) — the (N, 2nQ, 2n) one-hot
-        # candidate tensor this replaces was the largest tensor of the
-        # whole slot program.  Winners are bitwise-identical to the
-        # one-hot min-reduce: same keys, same min, per segment
-        # (tests/test_sim_memory.py pins the absence of the blowup).
-        rot = (pq32[None, :] + jnp.int32(slot)) % PQ       # tie-break perm
-        enc = tr["prio"].astype(key_dtype) * key_dtype(PQ) \
-            + rot.astype(key_dtype)                        # (N, PQ) < BIG
-        w_enc = jnp.stack(
-            [jnp.min(jnp.where(port_flat == ports8[p], enc, BIG), axis=1)
-             for p in range(P)], axis=1)                   # (N, P)
-        if link_ok is not None:
-            # a dead channel moves nothing: mask its winner away (packets
-            # requesting it — DOR through a fault — block in place)
-            w_enc = jnp.where(link_ok, w_enc, BIG)
-        if weighted:
-            # a weight-w channel stays held for w slots after a crossing:
-            # mask it out of arbitration exactly like a dead link while
-            # its busy countdown runs
-            w_enc = jnp.where(busy == 0, w_enc, BIG)
-        whas = w_enc < BIG
-        widx = jnp.where(
-            whas, (w_enc.astype(jnp.int32) % PQ - jnp.int32(slot)) % PQ, 0)
-        w_srcq = widx // Q                                 # queue it occupies
-        # a queue slot departs iff it IS its port's winner and the link moves
-        is_winner = gather_port(w_enc, BIG, port_flat) == enc  # (N, PQ)
+            # ---- winner per (node, out-port): segmented min over encoded keys --
+            # segment id = node·2n + requested_port, key = prio·PQ + rot —
+            # pre-drawn 8-bit threefry priorities (tr["prio"]) + a per-slot
+            # rotating tie-break keep the key narrow; priority collisions land
+            # on the rotating tie-break, so they carry no systematic
+            # queue-slot bias.  The segmented reduction is realized as one
+            # fused masked column-min per port bucket (2n static buckets)
+            # rather than jax.ops.segment_min, whose scatter-min lowering XLA
+            # CPU serializes (~17× slower at N=4096); either way every
+            # per-slot intermediate stays O(N·2nQ) — the (N, 2nQ, 2n) one-hot
+            # candidate tensor this replaces was the largest tensor of the
+            # whole slot program.  Winners are bitwise-identical to the
+            # one-hot min-reduce: same keys, same min, per segment
+            # (tests/test_sim_memory.py pins the absence of the blowup).
+            rot = (pq32[None, :] + jnp.int32(slot)) % PQ       # tie-break perm
+            enc = tr["prio"].astype(key_dtype) * key_dtype(PQ) \
+                + rot.astype(key_dtype)                        # (N, PQ) < BIG
+            w_enc = jnp.stack(
+                [jnp.min(jnp.where(port_flat == ports8[p], enc, BIG), axis=1)
+                 for p in range(P)], axis=1)                   # (N, P)
+            if link_ok is not None:
+                # a dead channel moves nothing: mask its winner away (packets
+                # requesting it — DOR through a fault — block in place)
+                w_enc = jnp.where(link_ok, w_enc, BIG)
+            if weighted:
+                # a weight-w channel stays held for w slots after a crossing:
+                # mask it out of arbitration exactly like a dead link while
+                # its busy countdown runs
+                w_enc = jnp.where(busy == 0, w_enc, BIG)
+            whas = w_enc < BIG
+            widx = jnp.where(
+                whas, (w_enc.astype(jnp.int32) % PQ - jnp.int32(slot)) % PQ, 0)
+            w_srcq = widx // Q                                 # queue it occupies
+            # a queue slot departs iff it IS its port's winner and the link moves
+            is_winner = gather_port(w_enc, BIG, port_flat) == enc  # (N, PQ)
 
-        flat_rec = rec.reshape(N, PQ, n)
-        flat_birth = birth.reshape(N, PQ)
+        with jax.named_scope("sim.link_view"):
+            flat_rec = rec.reshape(N, PQ, n)
+            flat_birth = birth.reshape(N, PQ)
 
-        # ---- per-link view at the receiver of in-port p ----
-        # (gathers composed: winner fields are read once, directly through
-        # the sender's winner index)
-        in_has = whas[sender, ports]                       # (N, P)
-        in_widx = widx[sender, ports]
-        in_rec = flat_rec[sender, in_widx]                 # (N, P, n)
-        in_birth = flat_birth[sender, in_widx]
-        in_srcq = in_widx // Q
-        rec_after = in_rec - hop[None]
-        done = jnp.abs(rec_after.astype(jnp.int32)).sum(-1) == 0
-        deliver = in_has & done
-        turning = in_srcq != ports[None]                   # entering this ring
-        need = jnp.where(turning, 2, 1)                    # bubble rule
-        free0 = Q - occ.sum(axis=2)                        # (N, P) per queue
+            # ---- per-link view at the receiver of in-port p ----
+            # (gathers composed: winner fields are read once, directly through
+            # the sender's winner index)
+            in_has = whas[sender, ports]                       # (N, P)
+            in_widx = widx[sender, ports]
+            in_rec = flat_rec[sender, in_widx]                 # (N, P, n)
+            in_birth = flat_birth[sender, in_widx]
+            in_srcq = in_widx // Q
+            rec_after = in_rec - hop[None]
+            done = jnp.abs(rec_after.astype(jnp.int32)).sum(-1) == 0
+            deliver = in_has & done
+            turning = in_srcq != ports[None]                   # entering this ring
+            need = jnp.where(turning, 2, 1)                    # bubble rule
+            free0 = Q - occ.sum(axis=2)                        # (N, P) per queue
 
         # ---- acceptance: exact sequential-sweep fixed point ----
-        # The reference resolves same-slot space reuse by sweeping ports in
-        # index order: in-port p sees slots vacated by winners that left
-        # through ports p' < p.  That recurrence needs only an (N, P)
-        # carry — per-queue vacancy counts and acceptance flags — so the
-        # heavy per-link quantities above stay one batched pass and the
-        # fixed point itself is a cheap `lax.scan` over the 2n port levels
-        # (bitwise-equal acceptance to the reference sweep given the same
-        # winners).
-        lvl_xs = dict(h=in_has.T, dn=done.T, f=free0.T, nd=need.T,
-                      dl=deliver.T, rx=receiver.T, wq=w_srcq.T, wh=whas.T,
-                      p=ports)
+        with jax.named_scope("sim.accept"):
+            # The reference resolves same-slot space reuse by sweeping ports in
+            # index order: in-port p sees slots vacated by winners that left
+            # through ports p' < p.  That recurrence needs only an (N, P)
+            # carry — per-queue vacancy counts and acceptance flags — so the
+            # heavy per-link quantities above stay one batched pass and the
+            # fixed point itself is a cheap `lax.scan` over the 2n port levels
+            # (bitwise-equal acceptance to the reference sweep given the same
+            # winners).
+            lvl_xs = dict(h=in_has.T, dn=done.T, f=free0.T, nd=need.T,
+                          dl=deliver.T, rx=receiver.T, wq=w_srcq.T, wh=whas.T,
+                          p=ports)
 
-        def level(vac, x):
-            acc_p = x["h"] & ~x["dn"] & (
-                x["f"] + jnp.take(vac, x["p"], axis=1) >= x["nd"])
-            # my port-p winner departs iff the packet moved at its receiver
-            dep_w = (x["dl"] | acc_p)[x["rx"]] & x["wh"]
-            vac = vac + jnp.where(
-                dep_w[:, None] & (x["wq"][:, None] == ports[None, :]), 1, 0)
-            return vac, acc_p
+            def level(vac, x):
+                acc_p = x["h"] & ~x["dn"] & (
+                    x["f"] + jnp.take(vac, x["p"], axis=1) >= x["nd"])
+                # my port-p winner departs iff the packet moved at its receiver
+                dep_w = (x["dl"] | acc_p)[x["rx"]] & x["wh"]
+                vac = vac + jnp.where(
+                    dep_w[:, None] & (x["wq"][:, None] == ports[None, :]), 1, 0)
+                return vac, acc_p
 
-        _, accT = jax.lax.scan(level, jnp.zeros((N, P), jnp.int32), lvl_xs)
-        acc = accT.T                                       # (N, P)
-        moved = deliver | acc
+            _, accT = jax.lax.scan(level, jnp.zeros((N, P), jnp.int32), lvl_xs)
+            acc = accT.T                                       # (N, P)
+            moved = deliver | acc
 
-        delivered = deliver.sum()
-        # latency telemetry measures only packets BORN in the measured
-        # window: warmup-era births carry queue-buildup ages that are not
-        # steady-state samples (the PR-6 warmup-bias fix).  birth >= warmup
-        # implies delivery slot > warmup, so these sums need no extra
-        # counted gate.
-        age = slot + 1 - in_birth                          # (N, P)
-        if weighted:
-            # delivery is counted at the win slot, but the packet still
-            # pays the final crossing: its true arrival is wgt[p]−1
-            # slots later (weight-1 adds 0 — identical arithmetic)
-            age = age + (wgt - 1)[None, :]
-        meas = deliver & (in_birth >= warmup)
-        lat_sum = jnp.where(meas, age, 0).sum()
-        lat_cnt = meas.sum()
+        with jax.named_scope("sim.finish"):
+            delivered = deliver.sum()
+            # latency telemetry measures only packets BORN in the measured
+            # window: warmup-era births carry queue-buildup ages that are not
+            # steady-state samples (the PR-6 warmup-bias fix).  birth >= warmup
+            # implies delivery slot > warmup, so these sums need no extra
+            # counted gate.
+            age = slot + 1 - in_birth                          # (N, P)
+            if weighted:
+                # delivery is counted at the win slot, but the packet still
+                # pays the final crossing: its true arrival is wgt[p]−1
+                # slots later (weight-1 adds 0 — identical arithmetic)
+                age = age + (wgt - 1)[None, :]
+            meas = deliver & (in_birth >= warmup)
+            lat_sum = jnp.where(meas, age, 0).sum()
+            lat_cnt = meas.sum()
 
         # ---- apply: clear departed slots + fused transit/injection write --
-        # Transit fills the FIRST free slot of the in-queue, injection the
-        # LAST free slot of its ring's queue; when both fire on the same
-        # queue the bubble rule guarantees ≥3 free post-clear slots, so
-        # the two one-hot masks never collide and every state array takes
-        # a single fused where-chain.
-        dep_port = moved[receiver, ports] & whas
-        dep_slot = is_winner & gather_port(dep_port, False, port_flat)
-        birth_cleared = jnp.where(dep_slot, -1, flat_birth).reshape(N, P, Q)
-        free_mask = birth_cleared < 0
-        qi = jnp.arange(Q)[None, None, :]
-        slot_f = jnp.argmax(free_mask, axis=2)             # (N, P) first free
-        slot_l = (Q - 1) - jnp.argmax(free_mask[:, :, ::-1], axis=2)
-        wmask = acc[:, :, None] & (qi == slot_f[:, :, None])
-        if express and not trivial:
-            port_in = _next_port_ext_ok(rec_after, ctx["pdim"],
-                                        ctx["psgn"], ctx["pspan"],
-                                        link_ok[:, None, :])
-        elif express:
-            port_in = _next_port_ext(rec_after, ctx["pdim"], ctx["psgn"],
-                                     ctx["pspan"])         # (N, P) next hop
-        elif trivial:
-            port_in, _, _ = _next_port(rec_after)          # (N, P) next hop
-        else:
-            port_in = policy_ports(rec_after, link_ok[:, None, :],
-                                   ctx["policy"])
+        with jax.named_scope("sim.apply"):
+            # Transit fills the FIRST free slot of the in-queue, injection the
+            # LAST free slot of its ring's queue; when both fire on the same
+            # queue the bubble rule guarantees ≥3 free post-clear slots, so
+            # the two one-hot masks never collide and every state array takes
+            # a single fused where-chain.
+            dep_port = moved[receiver, ports] & whas
+            dep_slot = is_winner & gather_port(dep_port, False, port_flat)
+            birth_cleared = jnp.where(dep_slot, -1, flat_birth).reshape(N, P, Q)
+            free_mask = birth_cleared < 0
+            qi = jnp.arange(Q)[None, None, :]
+            slot_f = jnp.argmax(free_mask, axis=2)             # (N, P) first free
+            slot_l = (Q - 1) - jnp.argmax(free_mask[:, :, ::-1], axis=2)
+            wmask = acc[:, :, None] & (qi == slot_f[:, :, None])
+            if express and not trivial:
+                port_in = _next_port_ext_ok(rec_after, ctx["pdim"],
+                                            ctx["psgn"], ctx["pspan"],
+                                            link_ok[:, None, :])
+            elif express:
+                port_in = _next_port_ext(rec_after, ctx["pdim"], ctx["psgn"],
+                                         ctx["pspan"])         # (N, P) next hop
+            elif trivial:
+                port_in, _, _ = _next_port(rec_after)          # (N, P) next hop
+            else:
+                port_in = policy_ports(rec_after, link_ok[:, None, :],
+                                       ctx["policy"])
 
-        # injection from pre-drawn traffic (after transit: in-flight
-        # traffic has priority; entering a ring costs 2 free slots)
-        want_new = tr["u"] < state["load"]
-        if scheduled:
-            want_new = want_new & inj_ok_e
-        elif not trivial:
-            want_new = want_new & state["inj_ok"]
-        want = want_new | (backlog0 > 0)
-        depcnt = dep_slot.reshape(N, P, Q).sum(axis=2)
-        freeq_post = free0 + depcnt - acc                  # after transit
-        inj_p = tr["p"]
-        if express and not trivial:
-            # the pre-drawn port table is liveness-ignorant; recompute
-            # the greedy weighted-DOR port against the current masks so
-            # a new packet never queues behind a dead express channel
-            # while its base port is live
-            inj_p = _next_port_ext_ok(tr["r"], ctx["pdim"], ctx["psgn"],
-                                      ctx["pspan"],
-                                      link_ok).astype(jnp.int8)
-        inj_port = inj_p.astype(jnp.int32)
-        if trivial:
-            drop = None
-            can = want & (jnp.take_along_axis(
-                freeq_post, inj_port[:, None], axis=1)[:, 0] >= 2) & tr["v"]
-        else:
-            # the drop mask is pattern-specific, so — like di_fixed — it
-            # lives in the STATE: the compiled runner stays shared across
-            # fixed patterns (the cache key only carries fixed-ness)
-            drop = want & ~(state["dst_live_fixed"][e] if scheduled
-                            else state["dst_live_fixed"])
-            ipc = jnp.minimum(inj_port, P - 1)             # clamp P sentinel
-            can = (want & ~drop & (jnp.take_along_axis(
-                freeq_post, ipc[:, None], axis=1)[:, 0] >= 2)
-                & tr["v"] & (inj_port < P))
-        imask = (can[:, None, None]
-                 & (ports8[None, :, None] == inj_p[:, None, None])
-                 & (qi == slot_l[:, :, None]))
-        backlog = backlog0 + want_new - can
-        if drop is not None:
-            backlog = backlog - drop
-        backlog = jnp.clip(backlog, 0, 1 << 30)
+            # injection from pre-drawn traffic (after transit: in-flight
+            # traffic has priority; entering a ring costs 2 free slots)
+            want_new = tr["u"] < state["load"]
+            if scheduled:
+                want_new = want_new & inj_ok_e
+            elif not trivial:
+                want_new = want_new & state["inj_ok"]
+            want = want_new | (backlog0 > 0)
+            depcnt = dep_slot.reshape(N, P, Q).sum(axis=2)
+            freeq_post = free0 + depcnt - acc                  # after transit
+            inj_p = tr["p"]
+            if express and not trivial:
+                # the pre-drawn port table is liveness-ignorant; recompute
+                # the greedy weighted-DOR port against the current masks so
+                # a new packet never queues behind a dead express channel
+                # while its base port is live
+                inj_p = _next_port_ext_ok(tr["r"], ctx["pdim"], ctx["psgn"],
+                                          ctx["pspan"],
+                                          link_ok).astype(jnp.int8)
+            inj_port = inj_p.astype(jnp.int32)
+            if trivial:
+                drop = None
+                can = want & (jnp.take_along_axis(
+                    freeq_post, inj_port[:, None], axis=1)[:, 0] >= 2) & tr["v"]
+            else:
+                # the drop mask is pattern-specific, so — like di_fixed — it
+                # lives in the STATE: the compiled runner stays shared across
+                # fixed patterns (the cache key only carries fixed-ness)
+                drop = want & ~(state["dst_live_fixed"][e] if scheduled
+                                else state["dst_live_fixed"])
+                ipc = jnp.minimum(inj_port, P - 1)             # clamp P sentinel
+                can = (want & ~drop & (jnp.take_along_axis(
+                    freeq_post, ipc[:, None], axis=1)[:, 0] >= 2)
+                    & tr["v"] & (inj_port < P))
+            imask = (can[:, None, None]
+                     & (ports8[None, :, None] == inj_p[:, None, None])
+                     & (qi == slot_l[:, :, None]))
+            backlog = backlog0 + want_new - can
+            if drop is not None:
+                backlog = backlog - drop
+            backlog = jnp.clip(backlog, 0, 1 << 30)
 
-        new_rec = jnp.where(
-            imask[..., None], tr["r"][:, None, None, :],
-            jnp.where(wmask[..., None], rec_after[:, :, None, :], rec))
-        new_birth = jnp.where(
-            imask, slot.astype(birth.dtype),
-            jnp.where(wmask, in_birth[:, :, None], birth_cleared))
-        new_port = jnp.where(
-            imask, inj_p[:, None, None],
-            jnp.where(wmask, port_in[:, :, None].astype(jnp.int8), port))
+            new_rec = jnp.where(
+                imask[..., None], tr["r"][:, None, None, :],
+                jnp.where(wmask[..., None], rec_after[:, :, None, :], rec))
+            new_birth = jnp.where(
+                imask, slot.astype(birth.dtype),
+                jnp.where(wmask, in_birth[:, :, None], birth_cleared))
+            new_port = jnp.where(
+                imask, inj_p[:, None, None],
+                jnp.where(wmask, port_in[:, :, None].astype(jnp.int8), port))
 
-        updates = dict(rec=new_rec, birth=new_birth, port=new_port,
-                       backlog=backlog)
-        if weighted:
-            # countdown bookkeeping: a departed slot's wait clears with
-            # it, an arriving packet starts at wgt[in-port]−1 (the write
-            # masks never collide with injection, which starts at 0 —
-            # crossing no link costs nothing), and the crossed channel's
-            # busy restarts at wgt−1 (blocked for the w−1 FOLLOWING slots)
-            wait_dec = jnp.where(dep_slot.reshape(N, P, Q), 0,
-                                 jnp.maximum(wait - 1, 0))
-            updates["wait"] = jnp.where(
-                imask, 0,
-                jnp.where(wmask, (wgt - 1)[None, :, None], wait_dec))
-            updates["busy"] = jnp.where(dep_port, wgt[None, :] - 1,
-                                        jnp.maximum(busy - 1, 0))
+            updates = dict(rec=new_rec, birth=new_birth, port=new_port,
+                           backlog=backlog)
+            if weighted:
+                # countdown bookkeeping: a departed slot's wait clears with
+                # it, an arriving packet starts at wgt[in-port]−1 (the write
+                # masks never collide with injection, which starts at 0 —
+                # crossing no link costs nothing), and the crossed channel's
+                # busy restarts at wgt−1 (blocked for the w−1 FOLLOWING slots)
+                wait_dec = jnp.where(dep_slot.reshape(N, P, Q), 0,
+                                     jnp.maximum(wait - 1, 0))
+                updates["wait"] = jnp.where(
+                    imask, 0,
+                    jnp.where(wmask, (wgt - 1)[None, :, None], wait_dec))
+                updates["busy"] = jnp.where(dep_port, wgt[None, :] - 1,
+                                            jnp.maximum(busy - 1, 0))
         if ctx["hist_bins"]:
-            updates["lat_hist"] = state["lat_hist"] + _bucket_counts(
-                age, meas, ctx["hist_bins"])
-        if not trivial:
-            # dead-channel audit: count every crossing (all slots, not just
-            # measured ones — "never" means never)
-            updates["link_use"] = state["link_use"] + dep_port.astype(jnp.int32)
-        out = _finish_slot(state, warmup, delivered, lat_sum, lat_cnt, can,
-                           drop, qdrop=qdrop, **updates)
-        return out, (_timeline_y(out, new_birth, dep_port, link_ok)
-                     if scheduled else None)
+            with jax.named_scope("sim.histogram"):
+                updates["lat_hist"] = state["lat_hist"] + _bucket_counts(
+                    age, meas, ctx["hist_bins"])
+        with jax.named_scope("sim.finish"):
+            if not trivial:
+                # dead-channel audit: count every crossing (all slots, not just
+                # measured ones — "never" means never)
+                updates["link_use"] = state["link_use"] + dep_port.astype(jnp.int32)
+            out = _finish_slot(state, warmup, delivered, lat_sum, lat_cnt, can,
+                               drop, qdrop=qdrop, **updates)
+            return out, (_timeline_y(out, new_birth, dep_port, link_ok)
+                         if scheduled else None)
 
     return slot_step
 
@@ -1380,197 +1388,208 @@ def _make_slot_step_vc_batched(ctx, warmup: int):
             # (their channels are dead and their injection is masked from
             # slot 0), so deadq ≡ False and the restore adds zero: the
             # static Scenario run stays bitwise-equal.
-            e = tr["epoch"]
-            link_ok = state["link_ok"][e]
-            inj_ok_e = state["inj_ok"][e]
-            deadq = (birth >= 0) & ~inj_ok_e[:, None, None, None]
-            qdrop = deadq.sum()
-            birth = jnp.where(deadq, -1, birth)
-            credit = credit + deadq.sum(axis=3)
-            backlog0 = jnp.where(inj_ok_e, state["backlog"], 0)
+            with jax.named_scope("sim.epoch"):
+                e = tr["epoch"]
+                link_ok = state["link_ok"][e]
+                inj_ok_e = state["inj_ok"][e]
+                deadq = (birth >= 0) & ~inj_ok_e[:, None, None, None]
+                qdrop = deadq.sum()
+                birth = jnp.where(deadq, -1, birth)
+                credit = credit + deadq.sum(axis=3)
+                backlog0 = jnp.where(inj_ok_e, state["backlog"], 0)
         else:
             link_ok = None if trivial else state["link_ok"]
             qdrop = None
             backlog0 = state["backlog"]
-        occ = birth >= 0                                   # (N, P, V, Q)
+        with jax.named_scope("sim.vc_select"):
+            occ = birth >= 0                                   # (N, P, V, Q)
 
-        # ---- per-packet (out-port, lane) request, credit-aware ----
-        # downstream credit view: what u sees for out-port p is the
-        # credit of ITS OWN queue at the receiver, (nbr[u,p], p, ·)
-        cd = credit[nbr, ports[None, :]]                   # (N, P, V)
-        lok = (jnp.ones((N, P), bool) if trivial else link_ok)
-        sel_port, sel_vc = credit_vc_select(
-            rec, lok[:, None, None, None, :],
-            cd[:, None, None, None, :, :], policy, rot=slot,
-            port_geom=port_geom, escape_fallback=esc_fb)
-        if weighted:
-            # multi-slot crossings: waiting packets are ineligible
-            busy, wait = state["busy"], state["wait"]
-            sel_port = jnp.where(occ & (wait == 0), sel_port, P)
-        else:
-            sel_port = jnp.where(occ, sel_port, P)         # sentinel if free
-        port_flat = sel_port.reshape(N, PVQ)
-        vc_flat = sel_vc.reshape(N, PVQ)
+            # ---- per-packet (out-port, lane) request, credit-aware ----
+            # downstream credit view: what u sees for out-port p is the
+            # credit of ITS OWN queue at the receiver, (nbr[u,p], p, ·)
+            cd = credit[nbr, ports[None, :]]                   # (N, P, V)
+            lok = (jnp.ones((N, P), bool) if trivial else link_ok)
+            sel_port, sel_vc = credit_vc_select(
+                rec, lok[:, None, None, None, :],
+                cd[:, None, None, None, :, :], policy, rot=slot,
+                port_geom=port_geom, escape_fallback=esc_fb)
+            if weighted:
+                # multi-slot crossings: waiting packets are ineligible
+                busy, wait = state["busy"], state["wait"]
+                sel_port = jnp.where(occ & (wait == 0), sel_port, P)
+            else:
+                sel_port = jnp.where(occ, sel_port, P)         # sentinel if free
+            port_flat = sel_port.reshape(N, PVQ)
+            vc_flat = sel_vc.reshape(N, PVQ)
 
         # ---- winner per (node, out-port): segmented min over lanes ----
-        rot = (pvq32[None, :] + jnp.int32(slot)) % PVQ
-        enc = tr["prio"].astype(key_dtype) * key_dtype(PVQ) \
-            + rot.astype(key_dtype)                        # (N, PVQ)
-        w_enc = jnp.stack(
-            [jnp.min(jnp.where(port_flat == p, enc, BIG), axis=1)
-             for p in range(P)], axis=1)                   # (N, P)
-        if link_ok is not None:
-            w_enc = jnp.where(link_ok, w_enc, BIG)
-        if weighted:
-            # a held (busy) physical channel arbitrates nothing this slot
-            w_enc = jnp.where(busy == 0, w_enc, BIG)
-        whas = w_enc < BIG
-        widx = jnp.where(
-            whas, (w_enc.astype(jnp.int32) % PVQ - jnp.int32(slot)) % PVQ,
-            0)
-        w_srcq = widx // Q                                 # queue id p·V+v
-        is_winner = gather_port(w_enc, BIG, port_flat) == enc
+        with jax.named_scope("sim.arbitrate"):
+            rot = (pvq32[None, :] + jnp.int32(slot)) % PVQ
+            enc = tr["prio"].astype(key_dtype) * key_dtype(PVQ) \
+                + rot.astype(key_dtype)                        # (N, PVQ)
+            w_enc = jnp.stack(
+                [jnp.min(jnp.where(port_flat == p, enc, BIG), axis=1)
+                 for p in range(P)], axis=1)                   # (N, P)
+            if link_ok is not None:
+                w_enc = jnp.where(link_ok, w_enc, BIG)
+            if weighted:
+                # a held (busy) physical channel arbitrates nothing this slot
+                w_enc = jnp.where(busy == 0, w_enc, BIG)
+            whas = w_enc < BIG
+            widx = jnp.where(
+                whas, (w_enc.astype(jnp.int32) % PVQ - jnp.int32(slot)) % PVQ,
+                0)
+            w_srcq = widx // Q                                 # queue id p·V+v
+            is_winner = gather_port(w_enc, BIG, port_flat) == enc
 
-        flat_rec = rec.reshape(N, PVQ, n)
-        flat_birth = birth.reshape(N, PVQ)
-        rows = jnp.arange(N)[:, None]
-        w_vc = jnp.take_along_axis(vc_flat, widx, axis=1)  # target lane
+            flat_rec = rec.reshape(N, PVQ, n)
+            flat_birth = birth.reshape(N, PVQ)
+            rows = jnp.arange(N)[:, None]
+            w_vc = jnp.take_along_axis(vc_flat, widx, axis=1)  # target lane
 
         # ---- per-link view at the receiver of in-port p ----
-        in_has = whas[sender, ports]                       # (N, P)
-        in_widx = widx[sender, ports]
-        in_rec = flat_rec[sender, in_widx]                 # (N, P, n)
-        in_birth = flat_birth[sender, in_widx]
-        in_srcq = w_srcq[sender, ports]                    # source queue id
-        in_vc = w_vc[sender, ports]                        # target lane
-        rec_after = in_rec - hop[None]
-        done = jnp.abs(rec_after.astype(jnp.int32)).sum(-1) == 0
-        deliver = in_has & done
-        tgt_q = ports[None, :] * V + in_vc                 # target queue id
-        # bubble rule per lane-ring: continuing in the SAME (port, lane)
-        # needs 1 free credit, entering (turn, lane switch) needs 2;
-        # credit-gated adaptive lanes need only 1 (Duato)
-        need = jnp.where(in_srcq == tgt_q, 1, 2)
-        if adaptive:
-            need = jnp.where(in_vc > 0, 1, need)
+        with jax.named_scope("sim.link_view"):
+            in_has = whas[sender, ports]                       # (N, P)
+            in_widx = widx[sender, ports]
+            in_rec = flat_rec[sender, in_widx]                 # (N, P, n)
+            in_birth = flat_birth[sender, in_widx]
+            in_srcq = w_srcq[sender, ports]                    # source queue id
+            in_vc = w_vc[sender, ports]                        # target lane
+            rec_after = in_rec - hop[None]
+            done = jnp.abs(rec_after.astype(jnp.int32)).sum(-1) == 0
+            deliver = in_has & done
+            tgt_q = ports[None, :] * V + in_vc                 # target queue id
+            # bubble rule per lane-ring: continuing in the SAME (port, lane)
+            # needs 1 free credit, entering (turn, lane switch) needs 2;
+            # credit-gated adaptive lanes need only 1 (Duato)
+            need = jnp.where(in_srcq == tgt_q, 1, 2)
+            if adaptive:
+                need = jnp.where(in_vc > 0, 1, need)
 
         # ---- acceptance: sequential-sweep fixed point over channels ----
-        # same recurrence as V=1, with a queue-granular (N, P·V) vacancy
-        # carry: each channel p writes only queue (w, p, lane), so lanes
-        # never collide and the carry stays tiny
-        credit_flat = credit.reshape(N, PV)
-        lvl_xs = dict(h=in_has.T, dn=done.T, nd=need.T, dl=deliver.T,
-                      rx=receiver.T, wq=w_srcq.T, wh=whas.T, tq=tgt_q.T)
+        with jax.named_scope("sim.accept"):
+            # same recurrence as V=1, with a queue-granular (N, P·V) vacancy
+            # carry: each channel p writes only queue (w, p, lane), so lanes
+            # never collide and the carry stays tiny
+            credit_flat = credit.reshape(N, PV)
+            lvl_xs = dict(h=in_has.T, dn=done.T, nd=need.T, dl=deliver.T,
+                          rx=receiver.T, wq=w_srcq.T, wh=whas.T, tq=tgt_q.T)
 
-        def level(vac, x):
-            freeq = take_q(credit_flat, x["tq"]) + take_q(vac, x["tq"])
-            acc_p = x["h"] & ~x["dn"] & (freeq >= x["nd"])
-            dep_w = (x["dl"] | acc_p)[x["rx"]] & x["wh"]
-            vac = vac + jnp.where(
-                dep_w[:, None] & (x["wq"][:, None] == qids[None, :]), 1, 0)
-            return vac, acc_p
+            def level(vac, x):
+                freeq = take_q(credit_flat, x["tq"]) + take_q(vac, x["tq"])
+                acc_p = x["h"] & ~x["dn"] & (freeq >= x["nd"])
+                dep_w = (x["dl"] | acc_p)[x["rx"]] & x["wh"]
+                vac = vac + jnp.where(
+                    dep_w[:, None] & (x["wq"][:, None] == qids[None, :]), 1, 0)
+                return vac, acc_p
 
-        _, accT = jax.lax.scan(level, jnp.zeros((N, PV), jnp.int32), lvl_xs)
-        acc = accT.T                                       # (N, P)
-        moved = deliver | acc
+            _, accT = jax.lax.scan(level, jnp.zeros((N, PV), jnp.int32), lvl_xs)
+            acc = accT.T                                       # (N, P)
+            moved = deliver | acc
 
-        delivered = deliver.sum()
-        age = slot + 1 - in_birth
-        if weighted:
-            # final-crossing cost: arrival is wgt[p]−1 slots after the win
-            age = age + (wgt - 1)[None, :]
-        meas = deliver & (in_birth >= warmup)
-        lat_sum = jnp.where(meas, age, 0).sum()
-        lat_cnt = meas.sum()
+        with jax.named_scope("sim.finish"):
+            delivered = deliver.sum()
+            age = slot + 1 - in_birth
+            if weighted:
+                # final-crossing cost: arrival is wgt[p]−1 slots after the win
+                age = age + (wgt - 1)[None, :]
+            meas = deliver & (in_birth >= warmup)
+            lat_sum = jnp.where(meas, age, 0).sum()
+            lat_cnt = meas.sum()
 
         # ---- apply: clears + one-hot transit/injection writes ----
-        dep_port = moved[receiver, ports] & whas
-        dep_slot = is_winner & gather_port(dep_port, False, port_flat)
-        birth_cleared = jnp.where(dep_slot, -1,
-                                  flat_birth).reshape(N, P, V, Q)
-        free_mask = birth_cleared < 0
-        qi = jnp.arange(Q)[None, None, None, :]
-        slot_f = jnp.argmax(free_mask, axis=3)             # (N, P, V)
-        slot_l = (Q - 1) - jnp.argmax(free_mask[..., ::-1], axis=3)
-        accv = acc[:, :, None] & (varange[None, None, :] == in_vc[:, :, None])
-        wmask = accv[..., None] & (qi == slot_f[..., None])
+        with jax.named_scope("sim.apply"):
+            dep_port = moved[receiver, ports] & whas
+            dep_slot = is_winner & gather_port(dep_port, False, port_flat)
+            birth_cleared = jnp.where(dep_slot, -1,
+                                      flat_birth).reshape(N, P, V, Q)
+            free_mask = birth_cleared < 0
+            qi = jnp.arange(Q)[None, None, None, :]
+            slot_f = jnp.argmax(free_mask, axis=3)             # (N, P, V)
+            slot_l = (Q - 1) - jnp.argmax(free_mask[..., ::-1], axis=3)
+            accv = acc[:, :, None] & (varange[None, None, :] == in_vc[:, :, None])
+            wmask = accv[..., None] & (qi == slot_f[..., None])
 
-        # ---- injection (after transit; local credits gate admission) --
-        want_new = tr["u"] < state["load"]
-        if scheduled:
-            want_new = want_new & inj_ok_e
-        elif not trivial:
-            want_new = want_new & state["inj_ok"]
-        want = want_new | (backlog0 > 0)
-        depcnt = dep_slot.reshape(N, P, V, Q).sum(axis=3)  # (N, P, V)
-        credit_post = credit + depcnt - accv.astype(jnp.int32)
-        inj_port, inj_vc = credit_vc_select(tr["r"], lok, credit_post,
-                                            policy, rot=slot,
-                                            port_geom=port_geom,
-                                            escape_fallback=esc_fb)
-        ipc = jnp.minimum(inj_port, P - 1)                 # clamp P sentinel
-        freesel = take_q(credit_post.reshape(N, PV), ipc * V + inj_vc)
-        can = want & (freesel >= 2) & tr["v"] & (inj_port < P)
-        if trivial:
-            drop = None
-        else:
-            drop = want & ~(state["dst_live_fixed"][e] if scheduled
-                            else state["dst_live_fixed"])
-            can = can & ~drop
-        imask = (can[:, None, None, None]
-                 & (ports[None, :, None, None] == ipc[:, None, None, None])
-                 & (varange[None, None, :, None]
-                    == inj_vc[:, None, None, None])
-                 & (qi == slot_l[..., None]))
-        backlog = backlog0 + want_new - can
-        if drop is not None:
-            backlog = backlog - drop
-        backlog = jnp.clip(backlog, 0, 1 << 30)
+            # ---- injection (after transit; local credits gate admission) --
+            want_new = tr["u"] < state["load"]
+            if scheduled:
+                want_new = want_new & inj_ok_e
+            elif not trivial:
+                want_new = want_new & state["inj_ok"]
+            want = want_new | (backlog0 > 0)
+            depcnt = dep_slot.reshape(N, P, V, Q).sum(axis=3)  # (N, P, V)
+            credit_post = credit + depcnt - accv.astype(jnp.int32)
+            inj_port, inj_vc = credit_vc_select(tr["r"], lok, credit_post,
+                                                policy, rot=slot,
+                                                port_geom=port_geom,
+                                                escape_fallback=esc_fb)
+            ipc = jnp.minimum(inj_port, P - 1)                 # clamp P sentinel
+            freesel = take_q(credit_post.reshape(N, PV), ipc * V + inj_vc)
+            can = want & (freesel >= 2) & tr["v"] & (inj_port < P)
+            if trivial:
+                drop = None
+            else:
+                drop = want & ~(state["dst_live_fixed"][e] if scheduled
+                                else state["dst_live_fixed"])
+                can = can & ~drop
+            imask = (can[:, None, None, None]
+                     & (ports[None, :, None, None] == ipc[:, None, None, None])
+                     & (varange[None, None, :, None]
+                        == inj_vc[:, None, None, None])
+                     & (qi == slot_l[..., None]))
+            backlog = backlog0 + want_new - can
+            if drop is not None:
+                backlog = backlog - drop
+            backlog = jnp.clip(backlog, 0, 1 << 30)
 
-        new_rec = jnp.where(
-            imask[..., None], tr["r"][:, None, None, None, :],
-            jnp.where(wmask[..., None], rec_after[:, :, None, None, :],
-                      rec))
-        new_birth = jnp.where(
-            imask, slot.astype(birth.dtype),
-            jnp.where(wmask, in_birth[:, :, None, None], birth_cleared))
-        new_credit = credit_post - imask.sum(axis=3)
+            new_rec = jnp.where(
+                imask[..., None], tr["r"][:, None, None, None, :],
+                jnp.where(wmask[..., None], rec_after[:, :, None, None, :],
+                          rec))
+            new_birth = jnp.where(
+                imask, slot.astype(birth.dtype),
+                jnp.where(wmask, in_birth[:, :, None, None], birth_cleared))
+            new_credit = credit_post - imask.sum(axis=3)
 
-        # per-lane telemetry: deliveries by the winner's SOURCE lane,
-        # injections (incl. drops — they count as injected) by the
-        # admitted lane; warmup-gated like the scalar counters
-        counted = slot >= warmup
-        src_vc = in_srcq % V
-        vc_del = (deliver[..., None]
-                  & (src_vc[..., None] == varange)).sum((0, 1))
-        injm = can if drop is None else (can | drop)
-        vc_inj = (injm[:, None] & (inj_vc[:, None] == varange)).sum(0)
+        with jax.named_scope("sim.finish"):
+            # per-lane telemetry: deliveries by the winner's SOURCE lane,
+            # injections (incl. drops — they count as injected) by the
+            # admitted lane; warmup-gated like the scalar counters
+            counted = slot >= warmup
+            src_vc = in_srcq % V
+            vc_del = (deliver[..., None]
+                      & (src_vc[..., None] == varange)).sum((0, 1))
+            injm = can if drop is None else (can | drop)
+            vc_inj = (injm[:, None] & (inj_vc[:, None] == varange)).sum(0)
 
-        updates = dict(
-            rec=new_rec, birth=new_birth, credit=new_credit,
-            backlog=backlog,
-            vc_delivered=state["vc_delivered"] + jnp.where(counted, vc_del,
-                                                           0),
-            vc_injected=state["vc_injected"] + jnp.where(counted, vc_inj,
-                                                         0))
+            updates = dict(
+                rec=new_rec, birth=new_birth, credit=new_credit,
+                backlog=backlog,
+                vc_delivered=state["vc_delivered"] + jnp.where(counted, vc_del,
+                                                               0),
+                vc_injected=state["vc_injected"] + jnp.where(counted, vc_inj,
+                                                             0))
         if weighted:
-            wait_dec = jnp.where(dep_slot.reshape(N, P, V, Q), 0,
-                                 jnp.maximum(wait - 1, 0))
-            updates["wait"] = jnp.where(
-                imask, 0, jnp.where(wmask, (wgt - 1)[None, :, None, None],
-                                    wait_dec))
-            updates["busy"] = jnp.where(dep_port, wgt[None, :] - 1,
-                                        jnp.maximum(busy - 1, 0))
+            with jax.named_scope("sim.apply"):
+                wait_dec = jnp.where(dep_slot.reshape(N, P, V, Q), 0,
+                                     jnp.maximum(wait - 1, 0))
+                updates["wait"] = jnp.where(
+                    imask, 0, jnp.where(wmask, (wgt - 1)[None, :, None, None],
+                                        wait_dec))
+                updates["busy"] = jnp.where(dep_port, wgt[None, :] - 1,
+                                            jnp.maximum(busy - 1, 0))
         if ctx["hist_bins"]:
-            updates["lat_hist"] = state["lat_hist"] + _bucket_counts(
-                age, meas, ctx["hist_bins"])
-        if not trivial:
-            updates["link_use"] = state["link_use"] + dep_port.astype(
-                jnp.int32)
-        out = _finish_slot(state, warmup, delivered, lat_sum, lat_cnt, can,
-                           drop, qdrop=qdrop, **updates)
-        return out, (_timeline_y(out, new_birth, dep_port, link_ok)
-                     if scheduled else None)
+            with jax.named_scope("sim.histogram"):
+                updates["lat_hist"] = state["lat_hist"] + _bucket_counts(
+                    age, meas, ctx["hist_bins"])
+        with jax.named_scope("sim.finish"):
+            if not trivial:
+                updates["link_use"] = state["link_use"] + dep_port.astype(
+                    jnp.int32)
+            out = _finish_slot(state, warmup, delivered, lat_sum, lat_cnt, can,
+                               drop, qdrop=qdrop, **updates)
+            return out, (_timeline_y(out, new_birth, dep_port, link_ok)
+                         if scheduled else None)
 
     return slot_step
 
@@ -2130,6 +2149,10 @@ _SHARED_STATE = ("dst_table", "di_fixed") + _SCEN_STATE
 # fault patterns of one structure share a single trace/compile
 TRACE_COUNTS: dict = {"batched": 0, "reference": 0, "fused": 0}
 
+# host spans of the public entries (`sim.*`, docs/simulator.md "Profiling
+# a run"): recorded only while a profiler trace is being taken
+_span = jax.profiler.TraceAnnotation
+
 
 def _get_runner(t: SimTables, ctx, *, slots: int, warmup: int, impl: str,
                 n_loads: int, n_seeds: int = 1, n_scen: int = 1):
@@ -2176,7 +2199,8 @@ def _get_runner(t: SimTables, ctx, *, slots: int, warmup: int, impl: str,
 
             def runner(st, key):
                 TRACE_COUNTS[impl] += 1
-                tr = _make_traffic(ctx, st, key, slots)
+                with jax.named_scope("sim.predraw"):
+                    tr = _make_traffic(ctx, st, key, slots)
                 if scheduled:
                     # the slot→epoch map is scanned alongside the traffic
                     # so each step sees its epoch as a scalar
@@ -2376,13 +2400,14 @@ def _sweep_plan(g: LatticeGraph, pattern: str, loads, *, slots, warmup,
     derived per scenario, via `_scenario_mask_fields`);
     `force_dead_nodes` gives every lane the dead-node program structure
     when any pattern in the sweep kills nodes.  `schedules` (a list of K
-    `CompiledSchedule`s, already bound to `slots`) is the transient
-    analogue: per-schedule epoch stacks are padded to a common E and
-    stacked on the same outermost axis — K timelines, one trace, one
-    compile."""
+    schedules, each compiled here against `slots` unless it already is)
+    is the transient analogue: per-schedule epoch stacks are padded to a
+    common E and stacked on the same outermost axis — K timelines, one
+    trace, one compile."""
     t = tables or build_tables(g, seed)
     ls = links if links is not None and not links.is_trivial else None
     if schedules is not None:
+        schedules = [ensure_compiled(c, g, slots, links) for c in schedules]
         E = max(c.E for c in schedules)
         fdn = any(c.has_dead_nodes for c in schedules)
         ctx = _make_ctx(t, g, pattern, seed, queue, schedule=schedules[0],
@@ -2423,9 +2448,10 @@ def _sweep_plan(g: LatticeGraph, pattern: str, loads, *, slots, warmup,
             m["link_ok"] = m["link_ok"] & ctx["structural"]
     sl = seed_list if seed_list is not None else [seed]
     L, S = len(loads), len(sl)
-    runner = _get_runner(t, ctx, slots=slots, warmup=warmup, impl=impl,
-                         n_loads=L, n_seeds=S,
-                         n_scen=1 if masks is None else len(masks))
+    with _span("sim.runner"):
+        runner = _get_runner(t, ctx, slots=slots, warmup=warmup, impl=impl,
+                             n_loads=L, n_seeds=S,
+                             n_scen=1 if masks is None else len(masks))
     state = _init_state(ctx, 0.0, impl, slots)
     if L > 1:
         state = {
@@ -2455,14 +2481,43 @@ def _sweep_plan(g: LatticeGraph, pattern: str, loads, *, slots, warmup,
         base = jax.random.PRNGKey(s + 17)
         return np.asarray(jax.random.fold_in(base, li) if L > 1 else base)
 
-    keys = np.stack([
-        np.stack([run_key(s, li) for s in sl])
-        for li in range(L)])                               # (L, S, 2)
-    if S == 1:
-        keys = keys[:, 0]
-    if L == 1:
-        keys = keys[0]
-    return runner, state, jnp.asarray(keys), t, ctx
+    with _span("sim.keys"):
+        keys = np.stack([
+            np.stack([run_key(s, li) for s in sl])
+            for li in range(L)])                           # (L, S, 2)
+        if S == 1:
+            keys = keys[:, 0]
+        if L == 1:
+            keys = keys[0]
+        keys = jnp.asarray(keys)
+    return runner, state, keys, t, ctx
+
+
+def _run(runner, state, keys):
+    """Dispatch one device program and wait for its outputs (`sim.run`);
+    the host conversion that follows would wait at the same point."""
+    with _span("sim.run"):
+        return jax.block_until_ready(runner(state, keys))
+
+
+def _sweep_grid(entry: str, g: LatticeGraph, pattern: str, loads, cfg,
+                seed_list, axes_sizes: tuple, **plan) -> np.ndarray:
+    """Plan, run and fetch one sweep device program under the entry's
+    host span: the shared body of the three sweep entries.  Returns the
+    `_result_grid` over `axes_sizes`; `plan` carries the entry's own
+    `_sweep_plan` arguments (scenario, scenarios or schedules)."""
+    with _span(entry, nodes=g.order, slots=cfg.slots,
+               lanes=int(np.prod(axes_sizes))):
+        with _span("sim.plan"):
+            runner, state, keys, t, _ = _sweep_plan(
+                g, pattern, loads, slots=cfg.slots, warmup=cfg.warmup,
+                queue=cfg.queue, seed=cfg.seed, seed_list=seed_list,
+                tables=cfg.tables, impl=cfg.impl, hist_bins=cfg.hist_bins,
+                vcs=cfg.vcs, credits=cfg.credits, links=cfg.links, **plan)
+        out = _run(runner, state, keys)
+        with _span("sim.fetch"):
+            return _result_grid(out, axes_sizes, cfg.impl, slots=cfg.slots,
+                                warmup=cfg.warmup, N=t.N)
 
 
 def simulate(g: LatticeGraph, pattern: str, load: float, *,
@@ -2526,24 +2581,33 @@ def simulate(g: LatticeGraph, pattern: str, load: float, *,
         config, slots=slots, warmup=warmup, queue=queue, seed=seed,
         tables=tables, impl=impl, scenario=scenario, schedule=schedule,
         hist_bins=hist_bins, vcs=vcs, credits=credits, links=links)
-    t = cfg.tables or build_tables(g, cfg.seed)
-    if cfg.schedule is not None:
-        ctx = _make_ctx(t, g, pattern, cfg.seed, cfg.queue,
-                        schedule=ensure_compiled(cfg.schedule, g,
-                                                 cfg.slots, cfg.links),
-                        hist_bins=cfg.hist_bins, vcs=cfg.vcs,
-                        credits=cfg.credits, links=cfg.links)
-    else:
-        ctx = _make_ctx(t, g, pattern, cfg.seed, cfg.queue, cfg.scenario,
-                        hist_bins=cfg.hist_bins, vcs=cfg.vcs,
-                        credits=cfg.credits, links=cfg.links)
-    runner = _get_runner(t, ctx, slots=cfg.slots, warmup=cfg.warmup,
-                         impl=cfg.impl, n_loads=1)
-    key = jax.random.PRNGKey(cfg.seed + 17)
-    if fold is not None:
-        key = jax.random.fold_in(key, fold)
-    out = runner(_init_state(ctx, load, cfg.impl, cfg.slots), key)
-    return _result(out, slots=cfg.slots, warmup=cfg.warmup, N=t.N)
+    with _span("sim.simulate", nodes=g.order, slots=cfg.slots, lanes=1):
+        with _span("sim.plan"):
+            t = cfg.tables or build_tables(g, cfg.seed)
+            if cfg.schedule is not None:
+                ctx = _make_ctx(t, g, pattern, cfg.seed, cfg.queue,
+                                schedule=ensure_compiled(cfg.schedule, g,
+                                                         cfg.slots,
+                                                         cfg.links),
+                                hist_bins=cfg.hist_bins, vcs=cfg.vcs,
+                                credits=cfg.credits, links=cfg.links)
+            else:
+                ctx = _make_ctx(t, g, pattern, cfg.seed, cfg.queue,
+                                cfg.scenario, hist_bins=cfg.hist_bins,
+                                vcs=cfg.vcs, credits=cfg.credits,
+                                links=cfg.links)
+            with _span("sim.runner"):
+                runner = _get_runner(t, ctx, slots=cfg.slots,
+                                     warmup=cfg.warmup, impl=cfg.impl,
+                                     n_loads=1)
+            with _span("sim.keys"):
+                key = jax.random.PRNGKey(cfg.seed + 17)
+                if fold is not None:
+                    key = jax.random.fold_in(key, fold)
+            state = _init_state(ctx, load, cfg.impl, cfg.slots)
+        out = _run(runner, state, key)
+        with _span("sim.fetch"):
+            return _result(out, slots=cfg.slots, warmup=cfg.warmup, N=t.N)
 
 
 def simulate_sweep(g: LatticeGraph, pattern: str, loads, *,
@@ -2580,19 +2644,11 @@ def simulate_sweep(g: LatticeGraph, pattern: str, loads, *,
     sl = _seed_list(cfg.seed, seeds)
     if sl is None and len(loads) == 1:
         return [simulate(g, pattern, loads[0], config=cfg)]
-    runner, state, keys, t, _ = _sweep_plan(
-        g, pattern, loads, slots=cfg.slots, warmup=cfg.warmup,
-        queue=cfg.queue, seed=cfg.seed, seed_list=sl, tables=cfg.tables,
-        impl=cfg.impl, scenario=cfg.scenario,
-        schedules=(None if cfg.schedule is None
-                   else [ensure_compiled(cfg.schedule, g, cfg.slots,
-                                         cfg.links)]),
-        hist_bins=cfg.hist_bins, vcs=cfg.vcs, credits=cfg.credits,
-        links=cfg.links)
-    out = runner(state, keys)
     L, S = len(loads), len(sl or [cfg.seed])
-    res = _result_grid(out, (L, S), cfg.impl, slots=cfg.slots,
-                       warmup=cfg.warmup, N=t.N)
+    res = _sweep_grid(
+        "sim.sweep", g, pattern, loads, cfg, sl, (L, S),
+        scenario=cfg.scenario,
+        schedules=None if cfg.schedule is None else [cfg.schedule])
     if sl is None:
         return [res[li, 0] for li in range(L)]
     return SweepStats(loads=tuple(loads), seeds=tuple(sl),
@@ -2665,16 +2721,9 @@ def simulate_scenario_sweep(g: LatticeGraph, pattern: str, scenarios,
             "destination sampling differs structurally — sweep separately")
     loads = [float(l) for l in np.asarray(loads).ravel()]
     sl = _seed_list(cfg.seed, seeds)
-    runner, state, keys, t, _ = _sweep_plan(
-        g, pattern, loads, slots=cfg.slots, warmup=cfg.warmup,
-        queue=cfg.queue, seed=cfg.seed, seed_list=sl, tables=cfg.tables,
-        impl=cfg.impl, scenario=None, scenarios=scenarios,
-        hist_bins=cfg.hist_bins, vcs=cfg.vcs, credits=cfg.credits,
-        links=cfg.links)
-    out = runner(state, keys)
     K, L, S = len(scenarios), len(loads), len(sl or [cfg.seed])
-    res = _result_grid(out, (K, L, S), cfg.impl, slots=cfg.slots,
-                       warmup=cfg.warmup, N=t.N)
+    res = _sweep_grid("sim.scenario_sweep", g, pattern, loads, cfg, sl,
+                      (K, L, S), scenario=None, scenarios=scenarios)
     results = []
     for ki in range(K):
         if sl is None:
@@ -2749,18 +2798,9 @@ def simulate_schedule_sweep(g: LatticeGraph, pattern: str, schedules,
                      for s in schedules]
     loads = [float(l) for l in np.asarray(loads).ravel()]
     sl = _seed_list(cfg.seed, seeds)
-    compiled = [ensure_compiled(s, g, cfg.slots, cfg.links)
-                for s in schedules]
-    runner, state, keys, t, _ = _sweep_plan(
-        g, pattern, loads, slots=cfg.slots, warmup=cfg.warmup,
-        queue=cfg.queue, seed=cfg.seed, seed_list=sl, tables=cfg.tables,
-        impl=cfg.impl, scenario=None, schedules=compiled,
-        hist_bins=cfg.hist_bins, vcs=cfg.vcs, credits=cfg.credits,
-        links=cfg.links)
-    out = runner(state, keys)
-    K, L, S = len(compiled), len(loads), len(sl or [cfg.seed])
-    res = _result_grid(out, (K, L, S), cfg.impl, slots=cfg.slots,
-                       warmup=cfg.warmup, N=t.N)
+    K, L, S = len(schedules), len(loads), len(sl or [cfg.seed])
+    res = _sweep_grid("sim.schedule_sweep", g, pattern, loads, cfg, sl,
+                      (K, L, S), scenario=None, schedules=schedules)
     results = []
     for ki in range(K):
         if sl is None:

@@ -8,6 +8,7 @@ test worker collects the same tests and only the worker that runs this
 file loads the TPU compiler.
 """
 import os
+import re
 
 import jax
 import numpy as np
@@ -53,16 +54,21 @@ def no_persistent_cache():
     compilation_cache.reset_cache()
 
 
-def compile_for_chip(sharding, g, loads, **plan):
-    """Compile the batched sweep runner of `g` for the described chip and
-    return its memory analysis."""
+def compiled_for_chip(sharding, g, loads, **plan):
+    """The batched sweep runner of `g`, compiled for the described chip."""
     runner, state, keys, _, _ = _sweep_plan(
         g, "uniform", loads, queue=4, seed=0, tables=build_tables(g),
         impl="batched", scenario=None, **plan)
     shapes = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
                                        sharding=sharding), (state, keys))
-    return runner.lower(*shapes).compile().memory_analysis()
+    return runner.lower(*shapes).compile()
+
+
+def compile_for_chip(sharding, g, loads, **plan):
+    """Compile the batched sweep runner of `g` for the described chip and
+    return its memory analysis."""
+    return compiled_for_chip(sharding, g, loads, **plan).memory_analysis()
 
 
 def device_bytes(m) -> int:
@@ -88,3 +94,22 @@ def test_vc_flap_runner_compiles(one_chip):
                          seed_list=None, hist_bins=64, vcs=2,
                          schedules=[ensure_compiled(flap, g, 256)])
     assert 0 < device_bytes(m) < HBM_BYTES
+
+
+@pytest.mark.parametrize("vcs", [1, 2])
+def test_phase_scopes_survive_the_chip_compiler(one_chip, vcs):
+    """Every `sim.*` phase scope of the slot step is still in the op
+    metadata of the program the chip's compiler builds, which is where
+    the profiler reads it from (docs/simulator.md, "Profiling a run")."""
+    g = Torus(4, 4, 2)
+    plan = dict(slots=16, warmup=0, seed_list=None, hist_bins=16)
+    want = {"sim.predraw", "sim.arbitrate", "sim.link_view", "sim.accept",
+            "sim.apply", "sim.histogram", "sim.finish"}
+    if vcs > 1:
+        flap = FaultSchedule.link_flap((0, 0), 4, 10, policy="adaptive")
+        plan.update(vcs=2, credits=4, schedules=[flap])
+        want |= {"sim.epoch", "sim.vc_select"}
+    text = compiled_for_chip(one_chip, g, (0.4,), **plan).as_text()
+    stacks = re.findall(r'op_name="([^"]*)"', text)
+    found = {m for st in stacks for m in re.findall(r"sim\.[a-z_]+", st)}
+    assert want <= found, want - found
