@@ -623,10 +623,23 @@ def _finish_slot(state, counted_from, delivered, lat_sum, lat_cnt, can,
     return out
 
 
+def _port_lookup(per_port, fill, port_flat):
+    """(N, P) per-port values → (N, S) per-slot values through each queue
+    slot's requested port; sentinel port P reads `fill`.  A where-chain
+    over the P static ports, elementwise, so it fuses into one loop
+    instead of an element gather per slot; bitwise equal to a
+    `take_along_axis` over the table padded with a `fill` column."""
+    out = jnp.full(port_flat.shape, fill, per_port.dtype)
+    for p in range(per_port.shape[1]):
+        out = jnp.where(port_flat == p, per_port[:, p:p + 1], out)
+    return out
+
+
 def _make_slot_step_batched(ctx, warmup: int):
     """One simulated slot with NO Python loop over ports and NO scatters
-    (XLA CPU serializes scatter updates; everything here is gathers,
-    one-hot masks and small reductions):
+    (XLA CPU serializes scatter updates; everything here is node-axis
+    gathers, per-port select chains, one-hot masks and small reductions;
+    no element gather runs at every queue slot):
 
       * winner per (node, out-port): a segmented min over the N·2nQ
         encoded priority keys (segment id = node·2n + requested port,
@@ -686,14 +699,6 @@ def _make_slot_step_batched(ctx, warmup: int):
     pq32 = jnp.arange(PQ, dtype=jnp.int32)
     ports8 = jnp.arange(P, dtype=jnp.int8)
     NO_PORT = jnp.int8(P)
-
-    def gather_port(per_port, fill, port_flat):
-        """(N, P) per-out-port values → (N, PQ) per-slot values through each
-        queue slot's requested port (sentinel port P reads `fill`)."""
-        padded = jnp.concatenate(
-            [per_port, jnp.full((N, 1), fill, per_port.dtype)], axis=1)
-        return jnp.take_along_axis(padded, port_flat.astype(jnp.int32),
-                                   axis=1)
 
     scheduled = ctx.get("scheduled", False)
 
@@ -800,8 +805,6 @@ def _make_slot_step_batched(ctx, warmup: int):
             widx = jnp.where(
                 whas, (w_enc.astype(jnp.int32) % PQ - jnp.int32(slot)) % PQ, 0)
             w_srcq = widx // Q                                 # queue it occupies
-            # a queue slot departs iff it IS its port's winner and the link moves
-            is_winner = gather_port(w_enc, BIG, port_flat) == enc  # (N, PQ)
 
         with jax.named_scope("sim.link_view"):
             flat_rec = rec.reshape(N, PQ, n)
@@ -874,7 +877,11 @@ def _make_slot_step_batched(ctx, warmup: int):
             # the two one-hot masks never collide and every state array takes
             # a single fused where-chain.
             dep_port = moved[receiver, ports] & whas
-            dep_slot = is_winner & gather_port(dep_port, False, port_flat)
+            # a queue slot departs iff it IS its port's winner and the link
+            # moves: every enc < BIG, so a sentinel slot and a port that
+            # moves nothing (both read BIG) never match
+            dep_slot = _port_lookup(jnp.where(dep_port, w_enc, BIG), BIG,
+                                    port_flat) == enc          # (N, PQ)
             birth_cleared = jnp.where(dep_slot, -1, flat_birth).reshape(N, P, Q)
             free_mask = birth_cleared < 0
             qi = jnp.arange(Q)[None, None, :]
@@ -1365,12 +1372,6 @@ def _make_slot_step_vc_batched(ctx, warmup: int):
     if weighted:
         wgt = ctx["wgt"]                    # (P,) int32 slot costs
 
-    def gather_port(per_port, fill, port_flat):
-        padded = jnp.concatenate(
-            [per_port, jnp.full((N, 1), fill, per_port.dtype)], axis=1)
-        return jnp.take_along_axis(padded, port_flat.astype(jnp.int32),
-                                   axis=1)
-
     def take_q(arr_flat, qidx):
         """(N, PV) per-lane values gathered at a (N,) queue id each."""
         return jnp.take_along_axis(arr_flat, qidx[:, None], axis=1)[:, 0]
@@ -1440,7 +1441,6 @@ def _make_slot_step_vc_batched(ctx, warmup: int):
                 whas, (w_enc.astype(jnp.int32) % PVQ - jnp.int32(slot)) % PVQ,
                 0)
             w_srcq = widx // Q                                 # queue id p·V+v
-            is_winner = gather_port(w_enc, BIG, port_flat) == enc
 
             flat_rec = rec.reshape(N, PVQ, n)
             flat_birth = birth.reshape(N, PVQ)
@@ -1500,7 +1500,9 @@ def _make_slot_step_vc_batched(ctx, warmup: int):
         # ---- apply: clears + one-hot transit/injection writes ----
         with jax.named_scope("sim.apply"):
             dep_port = moved[receiver, ports] & whas
-            dep_slot = is_winner & gather_port(dep_port, False, port_flat)
+            # departs iff winner and moved (see the V=1 step)
+            dep_slot = _port_lookup(jnp.where(dep_port, w_enc, BIG), BIG,
+                                    port_flat) == enc          # (N, PVQ)
             birth_cleared = jnp.where(dep_slot, -1,
                                       flat_birth).reshape(N, P, V, Q)
             free_mask = birth_cleared < 0

@@ -65,6 +65,21 @@ def compiled_for_chip(sharding, g, loads, **plan):
     return runner.lower(*shapes).compile()
 
 
+@pytest.fixture(scope="module")
+def full_width_sweep(one_chip):
+    """chip_smoke.py's full-width sweep runner of a graph, compiled for the
+    described chip once per module."""
+    compiled = {}
+
+    def get(graph):
+        if graph not in compiled:
+            g = Torus(16, 8, 8, 8) if graph.startswith("T") else FourD_FCC(8)
+            assert g.order == 8192
+            compiled[graph] = compiled_for_chip(one_chip, g, LOADS, **SWEEP)
+        return compiled[graph]
+    return get
+
+
 def compile_for_chip(sharding, g, loads, **plan):
     """Compile the batched sweep runner of `g` for the described chip and
     return its memory analysis."""
@@ -77,11 +92,21 @@ def device_bytes(m) -> int:
 
 
 @pytest.mark.parametrize("graph", ["T(16,8,8,8)", "4D-FCC(8)"])
-def test_full_width_sweep_compiles_and_fits(one_chip, graph):
-    g = Torus(16, 8, 8, 8) if graph.startswith("T") else FourD_FCC(8)
-    assert g.order == 8192
-    m = compile_for_chip(one_chip, g, LOADS, **SWEEP)
+def test_full_width_sweep_compiles_and_fits(full_width_sweep, graph):
+    m = full_width_sweep(graph).memory_analysis()
     assert 0 < device_bytes(m) < HBM_BYTES, device_bytes(m)
+
+
+def test_full_width_fcc_sweep_has_no_per_slot_gather(full_width_sweep):
+    """The compiled 4D-FCC(8) sweep runner reads each queue slot's port
+    with selects: no gather's result is shaped [..., 8192, 32], one
+    element per queue slot (P·Q = 8·4) of each of the 8192 nodes.  Such
+    element gathers took two thirds of the chip's slot scan."""
+    text = full_width_sweep("4D-FCC(8)").as_text()
+    gathers = re.findall(r"= (\w+\[[\d,]*\])[^=]*? gather\(", text)
+    assert gathers, "the node-axis gathers should still be seen"
+    per_slot = [s for s in gathers if re.search(r"\b8192,32\]$", s)]
+    assert not per_slot, per_slot
 
 
 def test_vc_flap_runner_compiles(one_chip):
