@@ -6,6 +6,19 @@ A lane record holds every count a run reports: ``delivered``,
 latency histogram where the mix asks for one, and under the VC router
 the per-lane counters, the per-channel link use and the per-slot
 timeline.  `compare` counts the values in which two records differ.
+
+A configuration's ``faults`` is null, a ``link_flap`` (one undirected
+link down from ``down_at`` until ``up_at``) or ``link_sets``:
+
+    {"kind": "link_sets", "sets": [[{"link": [u, p], "down_at": d,
+                                     "up_at": r | null}, ...], ...]}
+
+K sets of undirected link events (`link_sets`), each swept as one lane
+group by the mix's ``simulate_scenario_sweep`` (static sets: every
+``down_at`` 0, every ``up_at`` null) or ``simulate_schedule_sweep``
+entry under the router's ``policy``; an empty set is the pristine lane.
+Their records nest sets × loads × seeds, the other entries' loads ×
+seeds; `lanes` flattens either.
 """
 from __future__ import annotations
 
@@ -27,9 +40,72 @@ def graph(config: dict):
     return getattr(core, topo["constructor"])(*topo["args"])
 
 
+def link_sets(config: dict, mix: Mix):
+    """The configuration's ``link_sets`` as K lists of ``((u, p),
+    down_at, up_at)`` events, checked against the mix's entry; None for
+    a configuration with other faults or none.  Refused, each with its
+    reason: a fault-sweep entry without ``link_sets`` and the reverse,
+    ``link_sets`` at V = 1, node events, and a timed event under
+    ``simulate_scenario_sweep``."""
+    faults = config.get("faults")
+    kind = None if faults is None else faults["kind"]
+    if kind not in (None, "link_flap", "link_sets"):
+        raise ValueError(f"unknown fault kind {kind!r}")
+    if (kind == "link_sets") != (mix.entry in traffic.FAULT_SWEEPS):
+        raise ValueError(
+            f"mix {mix.name}: the entries {', '.join(traffic.FAULT_SWEEPS)} "
+            f"and only they sweep a configuration's link_sets; entry "
+            f"{mix.entry} meets faults of kind {kind}")
+    if kind is None or kind == "link_flap":
+        return None
+    if config["router"]["vcs"] < 2:
+        raise ValueError("link_sets at V = 1: the reference has no faulted "
+                         "V = 1 router (it runs a fault under the VC router "
+                         "alone)")
+    if not faults["sets"]:
+        raise ValueError("link_sets needs at least one set (an empty set "
+                         "is the pristine lane)")
+    N, P = int(config["nodes"]), 2 * len(config["generator_matrix"])
+    sets = []
+    for k, events in enumerate(faults["sets"]):
+        sets.append([])
+        for ev in events:
+            if set(ev) != {"link", "down_at", "up_at"}:
+                raise ValueError(
+                    f"link_sets set {k}: {ev} is not a link event "
+                    f"{{link, down_at, up_at}}; node events are refused: "
+                    f"the reference has no dead-node destination draw")
+            (u, p), down, up = ev["link"], ev["down_at"], ev["up_at"]
+            if not (0 <= u < N and 0 <= p < P):
+                raise ValueError(f"link_sets set {k}: link {[u, p]} is not "
+                                 f"a (node < {N}, port < {P}) pair")
+            if down < 0 or (up is not None and up <= down):
+                raise ValueError(f"link_sets set {k}: link {[u, p]} needs "
+                                 f"0 <= down_at < up_at, got {down}, {up}")
+            if mix.entry == "simulate_scenario_sweep" and (down, up) != (
+                    0, None):
+                raise ValueError(
+                    f"mix {mix.name}: simulate_scenario_sweep runs static "
+                    f"patterns, so every event needs down_at 0 and up_at "
+                    f"null; set {k} times link {[u, p]} at {down}, {up}: "
+                    f"drive it with simulate_schedule_sweep")
+            sets[-1].append(((int(u), int(p)), int(down),
+                             None if up is None else int(up)))
+    return sets
+
+
 def node_slots(config: dict, mix: Mix) -> int:
     """Simulated node-slots of one call."""
-    return int(config["nodes"]) * mix.slots * mix.n_lanes()
+    sets = link_sets(config, mix)
+    return (int(config["nodes"]) * mix.slots * mix.n_lanes()
+            * (1 if sets is None else len(sets)))
+
+
+def lanes(records) -> list[dict]:
+    """The lane records of a call, flattened in the program's order."""
+    if isinstance(records, dict):
+        return [records]
+    return [r for row in records for r in lanes(row)]
 
 
 def _sim_config(config: dict, mix: Mix, tables, seed: int):
@@ -39,15 +115,28 @@ def _sim_config(config: dict, mix: Mix, tables, seed: int):
     kw = dict(slots=mix.slots, warmup=mix.warmup, hist_bins=mix.hist_bins,
               queue=router["queue"], vcs=router["vcs"],
               credits=router.get("credits"), seed=seed, tables=tables)
-    if faults is not None:
-        if faults["kind"] != "link_flap":
-            raise ValueError(f"unknown fault kind {faults['kind']!r}")
+    if faults is None:
+        if router["policy"] != "dor":
+            raise ValueError("a pristine configuration routes by DOR")
+    elif faults["kind"] == "link_flap":
         kw["schedule"] = FaultSchedule.link_flap(
             tuple(faults["link"]), faults["down_at"], faults["up_at"],
             policy=router["policy"])
-    elif router["policy"] != "dor":
-        raise ValueError("a pristine configuration routes by DOR")
     return SimConfig(**kw)
+
+
+def _fault_lanes(sets, entry: str, policy: str) -> list:
+    """One program input per set: a static `Scenario`, or a
+    `FaultSchedule` of its failures and repairs on a pristine base."""
+    from repro.core import FaultSchedule, Scenario
+    if entry == "simulate_scenario_sweep":
+        return [Scenario(dead_links=tuple(link for link, _, _ in s),
+                         policy=policy) for s in sets]
+    return [FaultSchedule(
+        events=tuple(ev for link, down, up in s for ev in (
+            ((down, "link_down", link),)
+            + (() if up is None else ((up, "link_up", link),)))),
+        base=Scenario(policy=policy)) for s in sets]
 
 
 def lane_record(r) -> dict:
@@ -76,14 +165,26 @@ def lane_record(r) -> dict:
 
 def program_call(config: dict, mix: Mix, tables, seed: int):
     """A closure that makes one call of the cell through the program's
-    public entry and returns its lane records, (loads × seeds) nested."""
+    public entry and returns its lane records, (loads × seeds) nested,
+    or (sets × loads × seeds) under a fault-sweep entry."""
+    from repro.core import simulation
     from repro.core.simulation import simulate, simulate_sweep
     g = graph(config)
+    sets = link_sets(config, mix)
     cfg = _sim_config(config, mix, tables, seed)
     if mix.entry == "simulate":
         return lambda: [[lane_record(
             simulate(g, mix.pattern, mix.loads[0], config=cfg))]]
     seeds = [seed + s for s in range(mix.seeds)]
+    if sets is not None:
+        entry = getattr(simulation, mix.entry)
+        inputs = _fault_lanes(sets, mix.entry, config["router"]["policy"])
+
+        def call():
+            return [[[lane_record(r) for r in row] for row in st.results]
+                    for st in entry(g, mix.pattern, inputs, mix.loads,
+                                    config=cfg, seeds=seeds)]
+        return call
 
     def call():
         st = simulate_sweep(g, mix.pattern, mix.loads, config=cfg,
@@ -99,40 +200,55 @@ def _lane_job(job):
 
 
 def reference_records(config: dict, mix: Mix, seed: int,
-                      want=reference.want_f32) -> list[list[dict]]:
-    """The reference's lane records for the same cell and seed.  The
-    lanes' traffic is drawn here on the host CPU; each lane then runs in
-    a worker process of its own, which imports numpy and the reference
-    alone (never JAX, so no worker reaches for the chip)."""
+                      want=reference.want_f32) -> list:
+    """The reference's lane records for the same cell and seed, nested
+    as `program_call`'s.  The lanes' traffic is drawn here on the host
+    CPU, once for all of a `link_sets` configuration's sets; each (set,
+    lane) then runs in a worker process of its own, which imports numpy
+    and the reference alone (never JAX, so no worker reaches for the
+    chip)."""
     lat = reference.Lattice(config["generator_matrix"])
     if lat.N != int(config["nodes"]):
         raise ValueError("the generator matrix disagrees with `nodes`")
     router = config["router"]
     V, Q = router["vcs"], router["queue"]
     faults = config.get("faults")
+    sets = link_sets(config, mix)
     grid = mix.lanes(seed)
     flat = [lane for row in grid for lane in row]
     kw = dict(slots=mix.slots, warmup=mix.warmup, queue=Q,
               hist_bins=mix.hist_bins, want=want)
     if V == 1 and faults is None:
-        kind = "v1"
+        kind, masks = "v1", [None]
     else:
         kind = "vc"
-        kw.update(vcs=V, credits=router.get("credits") or Q,
-                  link_ok=(np.ones((mix.slots, lat.N, lat.P), bool)
-                           if faults is None else reference.link_flap_mask(
-                               lat, mix.slots, faults["link"],
-                               faults["down_at"], faults["up_at"])))
-    workers = max(1, min(len(flat), os.cpu_count() or 1))
-    with ThreadPoolExecutor(workers) as ex:
+        kw.update(vcs=V, credits=router.get("credits") or Q)
+        if sets is not None:
+            masks = [reference.link_events_mask(lat, mix.slots, s)
+                     for s in sets]
+        elif faults is not None:
+            masks = [reference.link_flap_mask(
+                lat, mix.slots, faults["link"], faults["down_at"],
+                faults["up_at"])]
+        else:
+            masks = [np.ones((mix.slots, lat.N, lat.P), bool)]
+    K = len(masks)
+    workers = max(1, min(len(flat) * K, os.cpu_count() or 1))
+    with ThreadPoolExecutor(min(workers, len(flat))) as ex:
         trs = list(ex.map(lambda lane: traffic.draw(
             lane, mix.slots, lat.N, lat.P * V * Q), flat))
-    jobs = [(kind, lat, lane.load, tr, kw) for lane, tr in zip(flat, trs)]
-    del trs
+    jobs = [(kind, lat, lane.load, tr,
+             kw if mask is None else dict(kw, link_ok=mask))
+            for mask in masks for lane, tr in zip(flat, trs)]
+    del trs, masks
     with ProcessPoolExecutor(workers, mp_context=get_context("spawn")) as ex:
         recs = list(ex.map(_lane_job, jobs))
+    if mix.entry == "simulate_scenario_sweep":
+        for r in recs:                 # the static entry keeps no timeline
+            del r["timeline"]
     it = iter(recs)
-    return [[next(it) for _ in row] for row in grid]
+    grids = [[[next(it) for _ in row] for row in grid] for _ in range(K)]
+    return grids if sets is not None else grids[0]
 
 
 def compare(got: dict, want: dict) -> int:

@@ -271,7 +271,11 @@ def run_vc_lane(lat: Lattice, load: float, tr: dict, *, slots: int,
     lanes per input port, credit-gated minimal-adaptive lanes 1..V-1 and
     a DOR escape lane 0), under the per-slot channel liveness
     `link_ok` (slots, N, P): a dead channel moves nothing and packets
-    that want it wait.  No node dies, so nothing is dropped.
+    that want it wait.  No node dies, so nothing is dropped.  The
+    harness builds `link_ok` from the configuration's faults: all live,
+    a `link_flap`, or one of a `link_sets` configuration's K sets of
+    link events (`link_events_mask`), which the `simulate_scenario_sweep`
+    and `simulate_schedule_sweep` entries run under the same traffic.
 
     Each packet re-chooses its (out-port, lane) every slot against the
     credits its candidate downstream queues advertise.  Acceptance needs
@@ -398,13 +402,26 @@ def run_vc_lane(lat: Lattice, load: float, tr: dict, *, slots: int,
     return c
 
 
+def link_events_mask(lat: Lattice, slots: int, events) -> np.ndarray:
+    """(slots, N, P) liveness of the channels under undirected link
+    events ``((u, p), down_at, up_at)``: link (u, p) and its reverse
+    (nbr[u, p], p ^ 1) fail from slot `down_at` and carry traffic again
+    from `up_at` (None: not within the run).  Failures and repairs apply
+    in slot order, those of one slot in the order listed, each failure
+    before its own repair; from its slot on, each sets the link's state,
+    so repairing a live link or failing a dead one changes nothing."""
+    timed = sorted(((at, live, link) for link, down_at, up_at in events
+                    for at, live in ((down_at, False), (up_at, True))
+                    if at is not None), key=lambda ev: ev[0])
+    ok = np.ones((slots, lat.N, lat.P), bool)
+    for at, live, (u, p) in timed:
+        ok[at:, u, p] = ok[at:, lat.nbr[u, p], p ^ 1] = live
+    return ok
+
+
 def link_flap_mask(lat: Lattice, slots: int, link, down_at: int,
                    up_at: int) -> np.ndarray:
     """(slots, N, P) liveness of a single undirected link (u, p) that is
     down from slot `down_at` until `up_at`: both directions, (u, p) and
     (nbr[u, p], p ^ 1), carry nothing in between."""
-    u, p = link
-    ok = np.ones((slots, lat.N, lat.P), bool)
-    ok[down_at:up_at, u, p] = False
-    ok[down_at:up_at, lat.nbr[u, p], p ^ 1] = False
-    return ok
+    return link_events_mask(lat, slots, [(link, down_at, up_at)])

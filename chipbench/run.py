@@ -187,10 +187,10 @@ def main(argv=None, root: Path = ROOT) -> int:
 
     # the check: after the window, on what every timed call returned
     ref = cellmod.reference_records(cell.config, cell.mix, args.seed)
-    ref_lanes = [r for row in ref for r in row]
+    ref_lanes = cellmod.lanes(ref)
     bad_values = failed = 0
     for out in outs:
-        for got, want in zip([r for row in out for r in row], ref_lanes):
+        for got, want in zip(cellmod.lanes(out), ref_lanes):
             n = cellmod.compare(got, want)
             bad_values += n
             failed += n > 0

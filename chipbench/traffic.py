@@ -7,6 +7,11 @@ drives it and its parameters:
   * ``"entry": "simulate_sweep"`` — ``loads`` × ``seeds`` lanes in one
     device program; lane (l, s) runs base seed ``--seed + s``;
   * ``"entry": "simulate"`` — one lane at ``load`` from ``--seed``;
+  * ``"entry": "simulate_scenario_sweep"`` or
+    ``"simulate_schedule_sweep"`` — the configuration's K ``link_sets``
+    (static patterns, or timelines of link failures and repairs) ×
+    ``loads`` × ``seeds`` lanes in one device program; every set runs
+    the same (load, seed) lanes of traffic;
 
 plus ``pattern``, ``slots``, ``warmup`` and ``hist_bins``.  Every run of
 a cell, whatever its seed, does the same amount of work: the seed
@@ -23,7 +28,9 @@ from __future__ import annotations
 import functools
 from dataclasses import dataclass
 
-ENTRIES = ("simulate_sweep", "simulate")
+ENTRIES = ("simulate_sweep", "simulate", "simulate_scenario_sweep",
+           "simulate_schedule_sweep")
+FAULT_SWEEPS = ENTRIES[2:]       # their lanes are the config's link_sets
 
 
 @dataclass(frozen=True)
@@ -48,7 +55,8 @@ class Mix:
     def from_dict(cls, name: str, d: dict) -> "Mix":
         entry = d["entry"]
         if entry not in ENTRIES:
-            raise ValueError(f"mix {name}: unknown entry {entry!r}")
+            raise ValueError(f"mix {name}: unknown entry {entry!r}; the "
+                             f"harness drives {', '.join(ENTRIES)}")
         if entry == "simulate":
             loads, seeds = (float(d["load"]),), 1
         else:

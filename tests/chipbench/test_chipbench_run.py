@@ -8,21 +8,33 @@ import json
 
 import pytest
 
+from chipbench import cell
 from chipbench import run as runmod
-from chipbench_testkit import ROOT, bench_copy, cut_config, run_cell
+from chipbench.spec import Benchmark
+from chipbench_testkit import (FAULT_CELLS, FAULT_LOADS, FAULT_SETS, ROOT,
+                               add_fault_cell, bench_copy, cut_config,
+                               run_cell)
 
 DOC = json.loads((ROOT / "BENCHMARK.json").read_text())
 WORKLOADS = [w["name"] for w in DOC["workloads"]]
-SWEEPS = [w["name"] for w in DOC["workloads"]
-          if json.loads((ROOT / "chipbench" / "traffic"
-                         / f"{w['traffic']}.json").read_text())["entry"]
-          == "simulate_sweep"]
+
+
+def _lanes_a_call(workload):
+    c = Benchmark(ROOT).cell(workload)
+    return cell.node_slots(c.config, c.mix) // (c.config["nodes"]
+                                                * c.mix.slots)
+
+
+SWEEPS = [w for w in WORKLOADS if _lanes_a_call(w) > 1]
 LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device", "checks"}
 
 
 @pytest.fixture(scope="module")
 def cut_root(tmp_path_factory):
-    return bench_copy(tmp_path_factory.mktemp("bench"))
+    root = bench_copy(tmp_path_factory.mktemp("bench"))
+    for entry in FAULT_CELLS:
+        add_fault_cell(root, entry)
+    return root
 
 
 def test_refuses_a_cpu_device(capsys):
@@ -99,13 +111,23 @@ def test_new_cell_needs_only_new_files_and_entries(tmp_path, monkeypatch,
         source="program_counter", layer="entry points",
         moves="node_slots_per_s", workloads=["fcc2.two-loads"]))
     (root / "BENCHMARK.json").write_text(json.dumps(doc))
+    added = {"BENCHMARK.json", "fcc4d-2-new.json", "uniform.two-loads.json",
+             "lanes_per_call.py"}
+    for entry in FAULT_CELLS:               # a fault sweep through each entry
+        added |= add_fault_cell(root, entry)[1]
     changed = {k for k, v in _digest(root).items() if before.get(k) != v}
-    assert changed == {p for p in changed if p.name in (
-        "BENCHMARK.json", "fcc4d-2-new.json", "uniform.two-loads.json",
-        "lanes_per_call.py")}
+    assert changed == {p for p in changed if p.name in added}
+    assert len(changed) == len(added)
     line = run_cell(root, "fcc2.two-loads", monkeypatch, capsys, trace=1)
     assert line["correct"] is True and line["attempted"] == 2
     assert line["metrics"]["lanes_per_call"]["value"] == 2.0
+    for entry, name in FAULT_CELLS.items():
+        line = run_cell(root, name, monkeypatch, capsys)
+        assert line["correct"] is True and line["failed"] == 0
+        assert line["attempted"] == (len(FAULT_SETS[entry]["sets"])
+                                     * len(FAULT_LOADS) * 1)
+        assert line["checks"]["mismatched_values"] == {"value": 0,
+                                                       "limit": 0}
 
 
 def _unchanged_step(make):
@@ -145,8 +167,9 @@ FAULTS = {
 
 
 # a one-lane call has no half batch to leave out; one chip, no exchange
-BROKEN = [(f, w) for f in sorted(FAULTS) for w in WORKLOADS
-          if f != "half_batch" or w in SWEEPS]
+BROKEN = [(f, w) for f in sorted(FAULTS)
+          for w in WORKLOADS + sorted(FAULT_CELLS.values())
+          if f != "half_batch" or w in SWEEPS + list(FAULT_CELLS.values())]
 
 
 @pytest.mark.parametrize("fault,workload", BROKEN)

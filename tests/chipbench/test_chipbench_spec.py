@@ -8,8 +8,9 @@ import re
 import numpy as np
 import pytest
 
-from chipbench import reference
+from chipbench import cell, reference
 from chipbench.spec import Benchmark
+from chipbench.traffic import Mix
 from chipbench_testkit import ROOT
 
 NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}")
@@ -127,3 +128,84 @@ def test_cells_are_unique_pairs():
     pairs = [(w["config"], w["traffic"]) for w in DOC["workloads"]]
     assert len(pairs) == len(set(pairs))
     assert {w["config"] for w in DOC["workloads"]} == set(CONFIGS)
+
+
+FIGS_5_8 = [[(load, s, li) for s in (0, 1)]
+            for li, load in enumerate((0.2, 0.4, 0.6, 0.8, 1.0))]
+# each cell's node-slots a call and its (loads × seeds) lane grid as
+# (load, seed offset from --seed, load index folded into the key)
+PINNED = {
+    "fcc8.uniform.sweep": (23592960, FIGS_5_8),
+    "t16888-vc2.linkflap": (2097152, [[(0.4, 0, None)]]),
+    "t16888.uniform.sweep": (23592960, FIGS_5_8),
+    "fcc8.uniform.steady": (33554432, [[(load, 0, li)] for li, load in
+                                       enumerate((0.5, 0.6, 0.8, 1.0))]),
+}
+
+
+@pytest.mark.parametrize("workload", sorted(PINNED))
+def test_cells_keep_their_work_and_lanes(workload):
+    c = Benchmark(ROOT).cell(workload)
+    seed = 2**31 + 5
+    assert cell.node_slots(c.config, c.mix) == PINNED[workload][0]
+    assert [[(lane.load, lane.seed - seed, lane.fold) for lane in row]
+            for row in c.mix.lanes(seed)] == PINNED[workload][1]
+
+
+def _sets_config(vcs=2, event=None):
+    """Cell 2's configuration with K = 2 link_sets: the pristine set and
+    one of `event` (a flap of link (0, 0) by default)."""
+    cfg = Benchmark(ROOT).config("torus-16x8x8x8-vc2")
+    event = event or dict(link=[0, 0], down_at=64, up_at=160)
+    cfg["router"] = dict(cfg["router"], vcs=vcs)
+    cfg["faults"] = dict(kind="link_sets", sets=[[], [event]])
+    return cfg
+
+
+def _mix(entry, **kw):
+    return Mix.from_dict("m", dict(dict(
+        entry=entry, pattern="uniform", loads=[0.4], seeds=1, slots=256,
+        warmup=0, hist_bins=64), **kw))
+
+
+REFUSED = {
+    "link_sets at V = 1": (
+        lambda: cell.link_sets(_sets_config(vcs=1),
+                               _mix("simulate_schedule_sweep")),
+        "V = 1: the reference has no faulted V = 1 router"),
+    "node event": (
+        lambda: cell.link_sets(
+            _sets_config(event=dict(node=7, down_at=64, up_at=None)),
+            _mix("simulate_schedule_sweep")),
+        "node events are refused: the reference has no dead-node"),
+    "timed event, scenario entry": (
+        lambda: cell.link_sets(_sets_config(),
+                               _mix("simulate_scenario_sweep")),
+        "every event needs down_at 0 and up_at null"),
+    "link_sets, plain sweep": (
+        lambda: cell.link_sets(_sets_config(), _mix("simulate_sweep")),
+        "and only they sweep a configuration's link_sets"),
+    "no set": (
+        lambda: cell.link_sets(dict(_sets_config(), faults=dict(
+            kind="link_sets", sets=[])), _mix("simulate_schedule_sweep")),
+        "link_sets needs at least one set"),
+    "unknown entry": (
+        lambda: _mix("simulate_explore"),
+        "unknown entry 'simulate_explore'; the harness drives"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(REFUSED))
+def test_refusals_say_why(case):
+    make, message = REFUSED[case]
+    with pytest.raises(ValueError, match=re.escape(message)):
+        make()
+
+
+def test_link_sets_read_as_events():
+    cfg = _sets_config()
+    assert cell.link_sets(cfg, _mix("simulate_schedule_sweep")) == [
+        [], [((0, 0), 64, 160)]]
+    assert cell.node_slots(cfg, _mix("simulate_schedule_sweep",
+                                     loads=[0.4, 0.8], seeds=3)) == (
+        8192 * 256 * 2 * 2 * 3)
