@@ -174,77 +174,126 @@ def credit_vc_select(rec, link_ok, credit, policy: str = "adaptive",
     """
     rec = jnp.asarray(rec)
     n = rec.shape[-1]
-    V = credit.shape[-1]
+    lead = rec.shape[:-1]
+    cols = [rec[..., d] for d in range(n)]
     if port_geom is None:
         P = 2 * n
-        prod = jnp.stack([rec > 0, rec < 0],
-                         axis=-1).reshape(rec.shape[:-1] + (P,))
-        dor = jnp.where(prod.any(-1), jnp.argmax(prod, axis=-1),
-                        P).astype(jnp.int32)
-        ortho0 = rec == 0
-        ortho = jnp.stack([ortho0, ortho0], axis=-1).reshape(prod.shape)
+        # DOR: the first nonzero dimension, by a chain from the last one
+        dor = jnp.int32(P)
+        for d in range(n - 1, -1, -1):
+            dor = jnp.where(cols[d] > 0, jnp.int32(2 * d),
+                            jnp.where(cols[d] < 0, jnp.int32(2 * d + 1), dor))
+        prod = [cols[p // 2] > 0 if p % 2 == 0 else cols[p // 2] < 0
+                for p in range(P)]
+        ortho = [cols[p // 2] == 0 for p in range(P)]
     else:
-        pdim = jnp.asarray(port_geom[0], jnp.int32)
-        psgn = jnp.asarray(port_geom[1], jnp.int32)
-        pspan = jnp.asarray(port_geom[2], jnp.int32)
-        P = int(pdim.shape[0])
-        rv = jnp.take(rec, pdim, axis=-1)                    # (..., P)
-        prod = (psgn * rv > 0) & (pspan <= jnp.abs(rv))
-        d0 = jnp.argmax(rec != 0, axis=-1).astype(jnp.int32)
-        cand = prod & (pdim == d0[..., None])
-        # largest-span LIVE fitting first-dim port; dead spans rank
-        # below every live one (base span-1 fits whenever rec[d0] != 0,
-        # so only a dead base port leaves the packet blocking on it)
-        okx = jnp.broadcast_to(link_ok, prod.shape)
-        dkey = jnp.where(cand, okx.astype(jnp.int32) * 4096 + pspan, -1)
-        dor = jnp.where(cand.any(-1), jnp.argmax(dkey, axis=-1),
-                        P).astype(jnp.int32)
-        ortho = rv == 0
-    credit = jnp.broadcast_to(credit, rec.shape[:-1] + (P, V))
+        pdim, psgn, pspan = (np.asarray(a).tolist() for a in port_geom)
+        P = len(pdim)
+        rv = [cols[d] for d in pdim]
+        prod = [((r > 0) if s > 0 else (r < 0) if s < 0
+                 else jnp.zeros(r.shape, bool))
+                & (jnp.abs(r).astype(jnp.int32) >= span)
+                for r, s, span in zip(rv, psgn, pspan)]
+        ortho = [r == 0 for r in rv]
+    ok = jnp.asarray(link_ok, bool)
+    ok = jnp.broadcast_to(ok, jnp.broadcast_shapes(ok.shape, (P,)))
+    ok = list(jnp.moveaxis(ok, -1, 0))
+    if port_geom is not None:
+        # largest-span LIVE fitting port of the first nonzero dimension;
+        # dead spans rank below every live one (base span-1 fits whenever
+        # rec[d0] != 0, so only a dead base port leaves the packet
+        # blocking on it).  First maximum, as an argmax takes it.
+        d0 = jnp.int32(0)
+        for d in range(n - 1, -1, -1):
+            d0 = jnp.where(cols[d] != 0, jnp.int32(d), d0)
+        cand = [prod[p] & (d0 == pdim[p]) for p in range(P)]
+        dkey = [jnp.where(cand[p], ok[p].astype(jnp.int32) * 4096 + pspan[p],
+                          -1) for p in range(P)]
+        best, dor, anyc = dkey[0], jnp.int32(0), cand[0]
+        for p in range(1, P):
+            up = dkey[p] > best
+            best = jnp.where(up, dkey[p], best)
+            dor = jnp.where(up, jnp.int32(p), dor)
+            anyc = anyc | cand[p]
+        dor = jnp.where(anyc, dor, jnp.int32(P))
+    credit = jnp.asarray(credit)
+    V = credit.shape[-1]
+    credit = jnp.broadcast_to(credit,
+                              jnp.broadcast_shapes(credit.shape, (P, V)))
     rot = jnp.asarray(rot, jnp.int32)
     if policy == "dor":
-        # max-credit lane of the DOR port; ties rotate with `rot`
-        cp = jnp.take_along_axis(
-            credit, jnp.minimum(dor, P - 1)[..., None, None], axis=-2
-        )[..., 0, :]
-        key = cp.astype(jnp.int32) * V + (jnp.arange(V) + rot) % V
-        return dor, jnp.argmax(key, axis=-1).astype(jnp.int32)
+        # max-credit lane of the DOR port (the last port stands in for
+        # the sentinel); keys are unique mod V, so their maximum names
+        # the lane, and ties rotate with `rot`
+        key = (credit.astype(jnp.int32) * V
+               + (jnp.arange(V, dtype=jnp.int32) + rot) % V)
+        kmax = None
+        for v in range(V):
+            kv = _at_port(dor, [key[..., p, v] for p in range(P - 1)],
+                          key[..., P - 1, v])
+            kmax = kv if kmax is None else jnp.maximum(kmax, kv)
+        vc = (kmax % V - rot % V) % V
+        return (jnp.broadcast_to(dor, lead).astype(jnp.int32),
+                jnp.broadcast_to(vc, lead).astype(jnp.int32))
     if policy not in ("adaptive", "escape"):
         raise ValueError(f"unknown routing policy {policy!r}")
     if V < 2:
         raise ValueError("credit_vc_select needs V >= 2 (VC0 is reserved "
                          "for the escape lane)")
-    ok = jnp.broadcast_to(link_ok, prod.shape)
     # adaptive candidates: productive live ports × lanes 1..V-1 with at
-    # least one free credit; score = credit, round-robin tie-break over
-    # the C = 2n·(V−1) flattened candidate slots
+    # least one free credit.  Candidate c = p·(V−1) + v−1 scores
+    # credit·C + (c + rot) mod C, unique among the C = P·(V−1) of them
+    # (−1 when ineligible), so the maximum key alone names the winner:
+    # the max-credit candidate, ties broken round-robin by `rot`.  The
+    # keys are scored at the credit view's own (per-node) shape, then
+    # masked per record, one static candidate at a time.
     C = P * (V - 1)
-    elig = (prod & ok)[..., None] & (credit[..., 1:] > 0)   # (..., P, V-1)
-    flat = elig.reshape(elig.shape[:-2] + (C,))
-    cred_flat = credit[..., 1:].reshape(flat.shape).astype(jnp.int32)
-    perm = (jnp.arange(C) + rot) % C
-    key = jnp.where(flat, cred_flat * C + perm, -1)
-    best = jnp.argmax(key, axis=-1)
-    has = jnp.take_along_axis(key, best[..., None], axis=-1)[..., 0] >= 0
+    perm = (jnp.arange(C, dtype=jnp.int32) + rot) % C
+    ckey = jnp.where(credit[..., 1:] > 0,
+                     credit[..., 1:].astype(jnp.int32) * C
+                     + perm.reshape(P, V - 1), -1)
+    kmax = None
+    for p in range(P):
+        live = prod[p] & ok[p]
+        for v in range(V - 1):
+            key = jnp.where(live, ckey[..., p, v], -1)
+            kmax = key if kmax is None else jnp.maximum(kmax, key)
+    has = kmax >= 0
+    best = (kmax % C - rot % C) % C
     esc = dor
     if escape_fallback:
         # fault-aware escape: a DEAD DOR port turns the escape request
         # into the PR 3 misroute — a live orthogonal-dimension port,
         # else the first live port — still on VC0 only.  Live DOR ports
         # are untouched, so pristine selections are bitwise-identical.
-        def _first(mask):
-            return jnp.where(mask.any(-1), jnp.argmax(mask, axis=-1),
-                             P).astype(jnp.int32)
-
-        dor_live = (dor < P) & jnp.take_along_axis(
-            ok, jnp.minimum(dor, P - 1)[..., None], axis=-1)[..., 0]
-        mis = _first(ortho & ok)
-        mis = jnp.where(mis < P, mis, _first(ok))
+        dor_live = _at_port(dor, ok, False)
+        mis = _first_port([o & k for o, k in zip(ortho, ok)])
+        mis = jnp.where(mis < P, mis, _first_port(ok))
         mis = jnp.where(dor < P, mis, jnp.int32(P))   # nothing to route
         esc = jnp.where(dor_live, dor, mis)
-    port = jnp.where(has, (best // (V - 1)).astype(jnp.int32), esc)
-    vc = jnp.where(has, (best % (V - 1) + 1).astype(jnp.int32), 0)
-    return port, vc
+    port = jnp.where(has, best // (V - 1), esc)
+    vc = jnp.where(has, best % (V - 1) + 1, 0)
+    return (jnp.broadcast_to(port, lead).astype(jnp.int32),
+            jnp.broadcast_to(vc, lead).astype(jnp.int32))
+
+
+def _at_port(port, per_port, fill):
+    """The value of `per_port[port]` (a list of broadcastable arrays, one
+    per port), `fill` for a port past the list: a select chain over the
+    static ports, elementwise, in place of an element gather."""
+    out = fill
+    for p in range(len(per_port) - 1, -1, -1):
+        out = jnp.where(port == p, per_port[p], out)
+    return out
+
+
+def _first_port(masks):
+    """The first port whose mask is set, len(masks) where none is: a
+    select chain from the last port to the first."""
+    out = jnp.int32(len(masks))
+    for p in range(len(masks) - 1, -1, -1):
+        out = jnp.where(masks[p], jnp.int32(p), out)
+    return out
 
 
 # ---------------------------------------------------------------------------

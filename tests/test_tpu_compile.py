@@ -11,12 +11,14 @@ import os
 import re
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import FaultSchedule, FourD_FCC, Torus
 from repro.core.fault_schedule import ensure_compiled
+from repro.core.routing_engine import credit_vc_select
 from repro.core.simulation import _sweep_plan, build_tables
 
 HBM_BYTES = 16e9          # one v5e chip
@@ -107,6 +109,45 @@ def test_full_width_fcc_sweep_has_no_per_slot_gather(full_width_sweep):
     assert gathers, "the node-axis gathers should still be seen"
     per_slot = [s for s in gathers if re.search(r"\b8192,32\]$", s)]
     assert not per_slot, per_slot
+
+
+@pytest.mark.parametrize("lead", [(8192, 8, 2, 4), (8192, 64)])
+def test_vc_select_has_no_per_slot_gather_or_argmax(one_chip, lead):
+    """The VC step's per-slot `credit_vc_select` at the T(16,8,8,8) V = 2
+    cell's shape (int8 records of 8192 nodes × 64 queue slots, as the
+    (N, P, V, Q) queues the step passes or a flat (N, P·V·Q) view, with
+    per-node credit and link views) decodes each slot's (port, lane) with
+    elementwise selects: no gather and no reduce has a result of one
+    element per queue slot (8192 · 64).  The argmax reduces and the
+    per-slot element gather took 71 % of that cell's slot scan."""
+    N, P, V, n = 8192, 8, 2, 4
+    view = (N,) + (1,) * (len(lead) - 1)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    select = jax.jit(lambda rec, ok, credit, rot: credit_vc_select(
+        rec, ok, credit, "adaptive", rot=rot))
+    text = select.lower(arg(lead + (n,), jnp.int8), arg(view + (P,), bool),
+                        arg(view + (P, V), jnp.int32),
+                        arg((), jnp.int32)).compile().as_text()
+    per_slot = N * P * V * 4
+
+    def per_slot_ops(opcode):
+        """Instructions `opcode` whose result (or a result of a tuple)
+        holds one element per queue slot."""
+        found = []
+        for line in text.splitlines():
+            m = re.search(rf" = (.*?) {opcode}\(", line)
+            if m and per_slot in [
+                    int(np.prod([int(d) for d in dims.split(",") if d]))
+                    for dims in re.findall(r"\[([\d,]*)\]", m.group(1))]:
+                found.append(m.group(1))
+        return found
+
+    assert per_slot_ops("select"), "the per-slot selects should be seen"
+    assert not per_slot_ops("gather")
+    assert not per_slot_ops("reduce")
 
 
 def test_vc_flap_runner_compiles(one_chip):

@@ -24,7 +24,7 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-from repro.core import BCC, FCC, RTT, Scenario, SimConfig, Torus
+from repro.core import BCC, FCC, RTT, LinkSpec, Scenario, SimConfig, Torus
 from repro.core.routing_engine import credit_vc_select
 from repro.core.simulation import (_init_state, _make_ctx,
                                    _make_slot_step_vc_batched,
@@ -371,3 +371,129 @@ def test_credit_vc_select_rejects_v1():
     with pytest.raises(ValueError, match="V >= 2"):
         credit_vc_select(np.array([1, 0]), np.ones(4, bool),
                          np.ones((4, 1), np.int32), "adaptive")
+
+
+# ---------------------------------------------------------------------------
+# credit_vc_select is exactly the documented rule (argmax formulation)
+# ---------------------------------------------------------------------------
+
+def _credit_vc_select_argmax(rec, link_ok, credit, policy, rot, port_geom,
+                             escape_fallback):
+    """The documented rule as argmax reduces over the candidate axes and
+    element gathers: the oracle of `credit_vc_select`'s select chains."""
+    import jax.numpy as jnp
+    rec = jnp.asarray(rec)
+    n = rec.shape[-1]
+    V = credit.shape[-1]
+    if port_geom is None:
+        P = 2 * n
+        prod = jnp.stack([rec > 0, rec < 0],
+                         axis=-1).reshape(rec.shape[:-1] + (P,))
+        dor = jnp.where(prod.any(-1), jnp.argmax(prod, axis=-1),
+                        P).astype(jnp.int32)
+        ortho0 = rec == 0
+        ortho = jnp.stack([ortho0, ortho0], axis=-1).reshape(prod.shape)
+    else:
+        pdim, psgn, pspan = (jnp.asarray(a, jnp.int32) for a in port_geom)
+        P = int(pdim.shape[0])
+        rv = jnp.take(rec, pdim, axis=-1)
+        prod = (psgn * rv > 0) & (pspan <= jnp.abs(rv))
+        d0 = jnp.argmax(rec != 0, axis=-1).astype(jnp.int32)
+        cand = prod & (pdim == d0[..., None])
+        okx = jnp.broadcast_to(link_ok, prod.shape)
+        dkey = jnp.where(cand, okx.astype(jnp.int32) * 4096 + pspan, -1)
+        dor = jnp.where(cand.any(-1), jnp.argmax(dkey, axis=-1),
+                        P).astype(jnp.int32)
+        ortho = rv == 0
+    credit = jnp.broadcast_to(credit, rec.shape[:-1] + (P, V))
+    rot = jnp.asarray(rot, jnp.int32)
+    if policy == "dor":
+        cp = jnp.take_along_axis(
+            credit, jnp.minimum(dor, P - 1)[..., None, None], axis=-2
+        )[..., 0, :]
+        key = cp.astype(jnp.int32) * V + (jnp.arange(V) + rot) % V
+        return dor, jnp.argmax(key, axis=-1).astype(jnp.int32)
+    ok = jnp.broadcast_to(link_ok, prod.shape)
+    C = P * (V - 1)
+    elig = (prod & ok)[..., None] & (credit[..., 1:] > 0)
+    flat = elig.reshape(elig.shape[:-2] + (C,))
+    cred_flat = credit[..., 1:].reshape(flat.shape).astype(jnp.int32)
+    perm = (jnp.arange(C) + rot) % C
+    key = jnp.where(flat, cred_flat * C + perm, -1)
+    best = jnp.argmax(key, axis=-1)
+    has = jnp.take_along_axis(key, best[..., None], axis=-1)[..., 0] >= 0
+    esc = dor
+    if escape_fallback:
+        def first(mask):
+            return jnp.where(mask.any(-1), jnp.argmax(mask, axis=-1),
+                             P).astype(jnp.int32)
+
+        dor_live = (dor < P) & jnp.take_along_axis(
+            ok, jnp.minimum(dor, P - 1)[..., None], axis=-1)[..., 0]
+        mis = first(ortho & ok)
+        mis = jnp.where(mis < P, mis, first(ok))
+        mis = jnp.where(dor < P, mis, jnp.int32(P))
+        esc = jnp.where(dor_live, dor, mis)
+    port = jnp.where(has, (best // (V - 1)).astype(jnp.int32), esc)
+    vc = jnp.where(has, (best % (V - 1) + 1).astype(jnp.int32), 0)
+    return port, vc
+
+
+def _selection_inputs(rng, lead, n, P, V, view):
+    """Records with zero rows and extreme components, link masks with
+    dead ports, and credits with equal values, all-zero rows and all-zero
+    views.  `view` gives the credit and link masks per node only, as the
+    VC step passes them: (N, 1, 1, 1, P[, V]) against (N, P, V, Q, n)."""
+    rec = rng.integers(-4, 5, lead + (n,)).astype(np.int8)
+    rec[rng.random(lead + (n,)) < 0.35] = 0
+    rec[rng.random(lead) < 0.1] = 0                     # nothing to route
+    rec[rng.random(lead + (n,)) < 0.02] = -128
+    rec[rng.random(lead + (n,)) < 0.02] = 127
+    per = (lead[:1] + (1,) * (len(lead) - 1)) if view else lead
+    link_ok = rng.random(per + (P,)) < 0.7
+    credit = rng.integers(0, 3, per + (P, V)).astype(np.int32)
+    credit[rng.random(per) < 0.2] = 0                  # no credit at all
+    credit[rng.random(per) < 0.2] = 2                  # every credit tied
+    return rec, link_ok, credit
+
+
+@pytest.mark.parametrize("shape", ["record", "batch", "queue_slots"])
+@pytest.mark.parametrize("express", [False, True])
+@pytest.mark.parametrize("escape_fallback", [False, True])
+@pytest.mark.parametrize("V", [2, 3])
+@pytest.mark.parametrize("policy", ["dor", "adaptive", "escape"])
+def test_credit_vc_select_is_the_argmax_rule(policy, V, escape_fallback,
+                                             express, shape):
+    """`credit_vc_select` returns exactly what argmax reduces and element
+    gathers over the candidate axes return, value for value and as int32:
+    equal-credit ties under several `rot`, no credit (the escape lane),
+    zero records (the sentinel port), dead DOR ports, V = 3 and an
+    express port axis."""
+    if express:
+        n = 2
+        ls = LinkSpec(express=((0, 2, 1), (0, 4, 2)))
+        geom = (ls.port_dims(n), ls.port_signs(n), ls.port_spans(n))
+        P = ls.num_ports(n)
+    else:
+        n, geom = 3, None
+        P = 2 * n
+    rng = np.random.default_rng([V, escape_fallback, express,
+                                 ["dor", "adaptive", "escape"].index(policy),
+                                 ["record", "batch", "queue_slots"].index(shape)])
+    if shape == "record":
+        cases = [_selection_inputs(rng, (), n, P, V, False) for _ in range(6)]
+    elif shape == "batch":
+        cases = [_selection_inputs(rng, (96,), n, P, V, False)]
+    else:
+        cases = [_selection_inputs(rng, (8, P, V, 2), n, P, V, True)]
+    for rec, link_ok, credit in cases:
+        for rot in (0, 1, 7, 1000003):
+            want = _credit_vc_select_argmax(rec, link_ok, credit, policy, rot,
+                                            geom, escape_fallback)
+            got = credit_vc_select(rec, link_ok, credit, policy, rot=rot,
+                                   port_geom=geom,
+                                   escape_fallback=escape_fallback)
+            for g, w in zip(got, want):
+                assert g.dtype == np.int32 and g.shape == w.shape
+                np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
